@@ -8,8 +8,8 @@ scaled-down shape and report:
   * the projected time at the paper's full shape (linear in n^2 * perms).
 
 Variants come from the engine registry (the unified s_W impl table): the
-jnp brute / tiled / permblock-matmul forms, plus the Pallas kernels in
-interpret mode (correctness-path timing, not TPU performance). The suite
+jnp brute / tiled / permblock-matmul forms, plus the Pallas kernels
+(interpreted on the CPU: correctness-path timing only). The suite
 also reports what the hardware-aware planner picks for this backend/shape.
 """
 
@@ -77,10 +77,11 @@ def run(emit):
                            backend=backend)
         emit(f"fig1/planner_paper_shape_{backend}", 0.0, f"impl={pl_b.impl}")
 
-    # Pallas kernels, interpret mode, smaller shape (interpreter overhead)
+    # Pallas kernels, smaller shape (interpreter overhead on the CPU)
+    how = "compiled" if jax.default_backend() == "tpu" else "interpret"
     m2s, gps, igs = _instance(n=256, p=8)
     for name in engine.names(kind="pallas"):
-        fn = engine.get(name).bound(tile_r=128, tile_c=128, perm_block=4)
+        fn = engine.get(name).bound(tile_r=128, tile_c=128, perm_block=8)
         t = time_fn(fn, m2s, gps, igs, iters=2, warmup=1).median
-        emit(f"fig1/{name}_interpret", t * 1e6,
-             "correctness-path timing (CPU interpreter, not TPU)")
+        emit(f"fig1/{name}_{how}", t * 1e6,
+             f"{how} on {jax.devices()[0].device_kind}")

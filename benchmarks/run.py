@@ -32,7 +32,7 @@ import jax
 from benchmarks import (fig1_sw_variants, permanova_roofline,
                         pipeline_scale, roofline_report, serve_bench,
                         stream_triad, sweep_scale)
-from repro import obs
+from repro import compile_cache, obs
 
 SUITES = {
     "fig1": fig1_sw_variants.run,
@@ -67,6 +67,7 @@ def main() -> None:
                     help="directory for BENCH_<suite>.json files")
     args = ap.parse_args()
     names = args.only.split(",") if args.only else list(SUITES)
+    compile_cache.enable()
 
     # counters only (no spans): retraces/compiles and traffic counters per
     # suite get stamped into BENCH_*.json without perturbing the timings

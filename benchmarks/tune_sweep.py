@@ -33,9 +33,11 @@ def _tile_grid(smoke: bool):
     """(tile_r, tile_c, feat_block, perm_block) candidates."""
     if smoke:
         return [(16, 16, 8, 4), (32, 32, 8, 4)]
+    # full sweep: tiles Mosaic accepts (lane edges of 128 multiples,
+    # perm blocks of 8 multiples)
     return [(tr, tc, fb, pb)
-            for tr, tc in ((32, 32), (64, 64), (128, 128), (64, 128))
-            for fb in (32, 128)
+            for tr, tc in ((128, 128), (256, 256), (128, 256))
+            for fb in (128, 256)
             for pb in (8, 16)]
 
 
@@ -75,7 +77,7 @@ def sweep(metric: str, backend: str, *, n: int, d: int, g: int,
         spec = _dreg.get_fused(name)
         if backend not in spec.backends and \
                 not (smoke and spec.kind == "pallas"):
-            # smoke keeps the megakernel in (interpret mode off TPU) so CI
+            # smoke keeps the megakernel in (interpreted on the CPU) so CI
             # exercises the tile grid + precision kernel bodies end to end
             continue
         # xla has no tile knobs: one config per precision
@@ -105,7 +107,9 @@ def sweep(metric: str, backend: str, *, n: int, d: int, g: int,
                     t0 = time.perf_counter()
                     run()
                     t = time.perf_counter() - t0
-                except Exception as exc:  # noqa: BLE001 — skip non-lowering
+                except Exception as exc:  # noqa: BLE001 — counted, logged
+                    _eplanner.record_candidate_failure(
+                        "tune_sweep", f"{name}[{tag}]", exc)
                     emit(f"# skip {name}[{tag}] tile={tile}: {exc}")
                     continue
                 emit(f"tune/{backend}/{name}/{tag}/"

@@ -27,6 +27,9 @@ import jax.numpy as jnp
 
 Array = jax.Array
 
+# Gram-trick distances cancel (|x|² + |y|² - 2x·y): full f32 on the MXU.
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 # ---------------------------------------------------------------------------
 # Row primitives: rows(xb, xprep) -> (block, n) distances.
@@ -52,7 +55,7 @@ def euclidean_rows(xb: Array, x: Array) -> Array:
     """(block, n) Euclidean distances via the Gram trick (MXU-friendly)."""
     sq_b = jnp.sum(xb * xb, axis=-1)[:, None]
     sq = jnp.sum(x * x, axis=-1)[None, :]
-    d2 = sq_b + sq - 2.0 * (xb @ x.T)
+    d2 = sq_b + sq - 2.0 * jnp.matmul(xb, x.T, precision=_HIGHEST)
     return jnp.sqrt(jnp.maximum(d2, 0.0))
 
 
@@ -66,7 +69,7 @@ def braycurtis_rows(xb: Array, x: Array) -> Array:
 def jaccard_rows(xb: Array, x: Array) -> Array:
     """(block, n) binary Jaccard on presence/absence (prepare casts x > 0;
     float multiply = AND, so the same kernel shape works on the MXU)."""
-    inter = xb @ x.T                                   # |A & B|
+    inter = jnp.matmul(xb, x.T, precision=_HIGHEST)    # |A & B|
     card_b = jnp.sum(xb, axis=-1)[:, None]
     card = jnp.sum(x, axis=-1)[None, :]
     union = card_b + card - inter                      # |A | B|
@@ -182,8 +185,11 @@ def distance_matrix(x: Array, metric: str = "braycurtis", **kw) -> Array:
 
 
 def _zero_diag(d: Array) -> Array:
-    n = d.shape[0]
-    return d * (1.0 - jnp.eye(n, dtype=d.dtype))
+    # an iota compare fuses into the consumer; an (n, n) eye would be a
+    # second matrix-sized buffer
+    i = jax.lax.broadcasted_iota(jnp.int32, d.shape, 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
+    return jnp.where(i == j, jnp.zeros((), d.dtype), d)
 
 
 def _blocked_rows(row_fn: Callable, x: Array, block: int) -> Array:

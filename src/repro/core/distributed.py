@@ -22,12 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.core import distance as _distance
 from repro.core import fstat, permutations, permanova as _permanova
-
-try:  # jax >= 0.5 exposes shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 Array = jax.Array
 
@@ -77,11 +73,11 @@ def make_sw_shard_fn(mesh: Mesh, *, impl: str = "matmul",
 
     The row-sharded partial is looked up in the engine registry: the exact
     impl's companion when it has one, else the nearest family member
-    (tiled -> brute rows, pallas_* -> matmul rows)."""
+    (tiled, pallas_brute -> brute rows; pallas_permblock -> matmul rows)."""
     from repro import engine  # deferred: engine imports core modules
     partial_fn = engine.get_sharded(impl)
-    tuning_key = ("perm_block" if partial_fn is fstat.sw_matmul_rows_partial
-                  else "block")
+    tuning_key = ("block" if partial_fn is fstat.sw_rows_partial
+                  else "perm_block")
 
     def shard_body(mat2_rows, grouping, inv_gs, key, n_perms_padded):
         n_local = mat2_rows.shape[0]
@@ -110,16 +106,53 @@ def sw_distributed(mesh: Mesh, mat2: Array, grouping: Array, inv_gs: Array,
     mat2p, _ = pad_to_multiple(mat2, model_ways, axis=0)
     n_groups = inv_gs.shape[0]
 
-    impl = resolve_impl(impl, mat2.shape[0], n_perms, n_groups)
+    impl = resolve_impl(impl, mat2.shape[1], n_perms, n_groups)
     body = make_sw_shard_fn(mesh, impl=impl, n_groups=n_groups,
                             perm_block=perm_block)
-    fn = _shard_map(
+    fn = jax.shard_map(
         functools.partial(body, n_perms_padded=n_perms_padded),
         mesh=mesh,
         in_specs=(P("model", None), P(), P(), P()),
         out_specs=P(perm_axes),
+        # a Pallas partial's kernel body mixes its (varying) blocks with
+        # unvarying iotas, which the varying-axes check rejects
+        check_vma=False,
     )
     return fn(mat2p, grouping, inv_gs, key)
+
+
+def distance_matrix_sharded(mesh: Mesh, x: Array, metric: str = "braycurtis",
+                            *, block: int = 256) -> Array:
+    """Distance matrix born row-sharded over 'model'.
+
+    Shard k builds rows [k * n_local, (k + 1) * n_local) against all n
+    samples in row blocks, so no device ever holds more than its own rows
+    (a D larger than one chip's memory never exists in one place). Rows
+    are padded to a multiple of the 'model' width: the result is
+    (n_pad, n), sharded P('model', None), with pad rows and the diagonal
+    exactly zero — the operand permanova_distributed takes as is."""
+    mdef = _distance.ROW_METRICS[metric]
+    xp = mdef.prepare(jnp.asarray(x, jnp.float32))
+    n, d = xp.shape
+    model_ways = mesh.shape["model"]
+    n_local = -(-n // model_ways)
+    block = min(block, n_local)
+    n_local = -(-n_local // block) * block
+    x_rows = jnp.pad(xp, ((0, n_local * model_ways - n), (0, 0)))
+
+    def body(rows, x_full):
+        def step(_, xb):
+            return None, mdef.rows(xb, x_full)
+        _, dr = jax.lax.scan(step, None, rows.reshape(-1, block, d))
+        gi = (jax.lax.axis_index("model") * n_local
+              + jnp.arange(n_local))[:, None]
+        gj = jnp.arange(n)[None, :]
+        return jnp.where((gi < n) & (gi != gj), dr.reshape(n_local, n), 0.0)
+
+    fn = jax.jit(jax.shard_map(body, mesh=mesh,
+                               in_specs=(P("model", None), P()),
+                               out_specs=P("model", None)))
+    return fn(x_rows, xp)
 
 
 def permanova_distributed(mesh: Mesh, dm: Array, grouping: Array, *,
@@ -128,6 +161,9 @@ def permanova_distributed(mesh: Mesh, dm: Array, grouping: Array, *,
                           impl: str = "matmul", perm_block: int = 64):
     """Distributed full PERMANOVA. Semantics match core.permanova.permanova
     (up to permutation count padding, which only adds extra null draws).
+
+    dm is (n, n), or (n_pad, n) with zero pad rows as built by
+    distance_matrix_sharded (already row-sharded over 'model').
 
     Label normalization routes through the design shim like every other
     entry point; only plain single-factor designs run here (strata /
@@ -144,13 +180,13 @@ def permanova_distributed(mesh: Mesh, dm: Array, grouping: Array, *,
             "single-factor designs; use engine.permanova_many(mesh=...) "
             "for strata/covariate/weighted designs")
     grouping = design.grouping
-    n = dm.shape[0]
+    n = dm.shape[1]
     n_groups = design.n_groups
     mat2 = dm * dm
     inv_gs = permutations.inv_group_sizes(grouping, n_groups)
     s_w_all = sw_distributed(mesh, mat2, grouping, inv_gs, key, n_perms + 1,
                              impl=impl, perm_block=perm_block)
-    s_t = _permanova.s_total(mat2)
+    s_t = jnp.sum(mat2) / 2.0 / n          # pad rows are zero
     f_all = _permanova.f_from_sw(s_w_all, s_t, n, n_groups)
     return _permanova.PermanovaResult(
         f_stat=f_all[0],

@@ -20,10 +20,11 @@ matrix of 1k^2..100k^2 elements. Variants implemented here:
   sw_brute             brute force over a batch of permutations (scan over
                        permutation blocks x vmap inside a block).
   sw_matmul            beyond-paper one-hot matmul reformulation: for a block
-                       of P permutations build E in {0,sqrt(w_g)}^{n x (P*G)}
-                       and compute s_W via M2 @ E on the MXU. Raises the
-                       arithmetic intensity per M2 byte from ~3/4 flop/B to
-                       ~P*G/2 flop/B (see DESIGN.md section 3).
+                       of P permutations build E in {0,1}^{n x (P*G)}, compute
+                       M2 @ E on the MXU and weight the per-group sums by w_g
+                       afterwards. Raises the arithmetic intensity per M2
+                       byte from ~3/4 flop/B to ~P*G/2 flop/B (see DESIGN.md
+                       section 3).
   sw_rows_partial      row-sharded partial statistic for the distributed
                        runner (each shard owns a row block; triangle masking
                        uses global row offsets).
@@ -44,6 +45,11 @@ import jax.numpy as jnp
 import numpy as np
 
 Array = jax.Array
+
+# The contractions below sum millions of f32 products into statistics
+# whose differences (s_T - s_W) cancel; on TPU a default-precision f32
+# matmul takes one bf16 pass, which is too coarse for that. Full f32.
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 # ---------------------------------------------------------------------------
@@ -192,20 +198,21 @@ def sw_tiled(mat2: Array, groupings: Array, inv_group_sizes: Array,
 # Beyond-paper: one-hot matmul (MXU) formulation.
 # ---------------------------------------------------------------------------
 
-def onehot_perm_factors(groupings_block: Array,
-                        inv_group_sizes: Array, dtype) -> Array:
-    """E[p,:,g] = sqrt(w_g) * 1[g_p[i] == g] — the (P, n, G) one-hot factor
-    shared by every matmul-form s_W variant."""
-    n_groups = inv_group_sizes.shape[0]
-    sqrt_w = jnp.sqrt(inv_group_sizes).astype(dtype)
-    e = jax.nn.one_hot(groupings_block, n_groups, dtype=dtype)
-    return e * sqrt_w[None, None, :]
+def onehot_perm_factors(groupings_block: Array, n_groups: int,
+                        dtype) -> Array:
+    """E[p,:,g] = 1[g_p[i] == g] — the (P, n, G) one-hot factor shared by
+    every matmul-form s_W variant. It carries no group weight: 0 and 1 are
+    exact in every MXU pass format, where a weight operand would be rounded
+    the same way for a whole group (a bias, not noise). The weights ride on
+    the row factor, outside the matmul (sw_matmul_contract)."""
+    return jax.nn.one_hot(groupings_block, n_groups, dtype=dtype)
 
 
-def sw_matmul_contract(mat2_rows: Array, e: Array, e_rows: Array) -> Array:
+def sw_matmul_contract(mat2_rows: Array, e: Array, e_rows: Array,
+                       inv_group_sizes: Array) -> Array:
     """The matmul-form contraction over a block of mat2 rows.
 
-    s[p] = 1/2 * sum_ig (M2_rows @ E[p])[i,g] * E_rows[p,i,g]
+    s[p] = 1/2 * sum_g w_g sum_i (M2_rows @ E[p])[i,g] * E_rows[p,i,g]
 
     e: (P, n, G) column factors over ALL samples; e_rows: (P, n_local, G)
     row factors aligned with mat2_rows (e itself for the full matrix, a
@@ -218,17 +225,18 @@ def sw_matmul_contract(mat2_rows: Array, e: Array, e_rows: Array) -> Array:
     p, n, g = e.shape
     n_local = mat2_rows.shape[0]
     e2d = jnp.transpose(e, (1, 0, 2)).reshape(n, p * g)    # (n, P*G)
-    y = mat2_rows @ e2d                                    # on MXU
-    s = jnp.sum(y.reshape(n_local, p, g)
-                * jnp.transpose(e_rows, (1, 0, 2)), axis=(0, 2))
+    y = jnp.matmul(mat2_rows, e2d, precision=HIGHEST)      # on MXU
+    w_rows = jnp.transpose(e_rows, (1, 0, 2)) * inv_group_sizes  # exact
+    s = jnp.sum(y.reshape(n_local, p, g) * w_rows, axis=(0, 2))
     return 0.5 * s
 
 
 def sw_matmul_block(mat2: Array, groupings_block: Array,
                     inv_group_sizes: Array) -> Array:
     """s_W for a block of P permutations via one big matmul."""
-    e = onehot_perm_factors(groupings_block, inv_group_sizes, mat2.dtype)
-    return sw_matmul_contract(mat2, e, e)
+    e = onehot_perm_factors(groupings_block, inv_group_sizes.shape[0],
+                            mat2.dtype)
+    return sw_matmul_contract(mat2, e, e, inv_group_sizes)
 
 
 def sw_matmul(mat2: Array, groupings: Array, inv_group_sizes: Array,
@@ -263,7 +271,10 @@ def sw_rows_partial(mat2_rows: Array, row_offset: Array, groupings: Array,
     n_local, n = mat2_rows.shape
 
     def one(grouping):
-        g_rows = jax.lax.dynamic_slice(grouping, (row_offset,), (n_local,))
+        # pad by one slab so the last shard's window never clamps back
+        # onto real rows when n is not a multiple of the shard count
+        g_rows = jax.lax.dynamic_slice(jnp.pad(grouping, (0, n_local)),
+                                       (row_offset,), (n_local,))
         w_row = inv_group_sizes[g_rows][:, None]
         same = grouping[None, :] == g_rows[:, None]
         gi = row_offset + jnp.arange(n_local)[:, None]
@@ -287,10 +298,15 @@ def sw_matmul_rows_partial(mat2_rows: Array, row_offset: Array,
     n_local, n = mat2_rows.shape
 
     def body(_, gb):  # gb: (P, n)
-        e = onehot_perm_factors(gb, inv_group_sizes, mat2_rows.dtype)
+        e = onehot_perm_factors(gb, inv_group_sizes.shape[0],
+                                mat2_rows.dtype)
         p, _, g = e.shape
-        e_rows = jax.lax.dynamic_slice(e, (0, row_offset, 0), (p, n_local, g))
-        return None, sw_matmul_contract(mat2_rows, e, e_rows)
+        # zero-padded by one slab: the last shard's window never clamps
+        e_pad = jnp.pad(e, ((0, 0), (0, n_local), (0, 0)))
+        e_rows = jax.lax.dynamic_slice(e_pad, (0, row_offset, 0),
+                                       (p, n_local, g))
+        return None, sw_matmul_contract(mat2_rows, e, e_rows,
+                                        inv_group_sizes)
 
     n_perms = groupings.shape[0]
     perm_block = min(perm_block, n_perms)
@@ -335,7 +351,7 @@ def sw_cols_contract(mat2_rows: Array, v: Array, v_rows: Array) -> Array:
     p, n, k = v.shape
     n_local = mat2_rows.shape[0]
     v2d = jnp.transpose(v, (1, 0, 2)).reshape(n, p * k)     # (n, P*K)
-    y = mat2_rows @ v2d                                     # on MXU
+    y = jnp.matmul(mat2_rows, v2d, precision=HIGHEST)       # on MXU
     s = jnp.sum(y.reshape(n_local, p, k)
                 * jnp.transpose(v_rows, (1, 0, 2)), axis=0)
     return 0.5 * s                                          # (P, K)
@@ -383,10 +399,10 @@ def sw_cols_contract_sparse(mat2_rows: Array, v: Array, v_rows: Array,
     only its supported sample columns of mat2_rows.
 
     Every skipped (row j, column k) term has v[p, j, k] == 0 exactly, so
-    each group's gathered contraction bit-matches the dense path (the
-    surviving addends keep their order; adding exact zeros is the
-    identity). With one group spanning all rows this degrades gracefully
-    to the dense contraction."""
+    each group's gathered contraction computes the dense path's sum; it
+    agrees to f32 rounding, since the shorter gathered matmul is blocked
+    (reassociated) differently by the backend. With one group spanning all
+    rows this degrades to the dense contraction itself."""
     p, n, k = v.shape
     if len(groups) == 1 and len(groups[0][1]) == n:
         return sw_cols_contract(mat2_rows, v, v_rows)
@@ -428,7 +444,8 @@ def sw_cols_brute(mat2: Array, vperms: Array, *, block: int = 16) -> Array:
     mat2 (the GPU-style Algorithm 3 analogue for dense designs)."""
     def one_block(m2, vb):
         return jax.vmap(
-            lambda v: 0.5 * jnp.einsum("ik,ij,jk->k", v, m2, v))(vb)
+            lambda v: 0.5 * jnp.einsum("ik,ij,jk->k", v, m2, v,
+                                       precision=HIGHEST))(vb)
     return _scan_v_blocks(one_block, mat2, vperms, block)
 
 

@@ -587,7 +587,7 @@ def _permanova_many_design(dms, groupings, *, covariates, strata, weights,
         args = tuple(jnp.take(a, wrap_idx, axis=0) for a in args)
         study_idx = wrap_idx
     # GLOBAL study index -> per-study key, folded ONCE before any sharding
-    # (jax 0.4.x shard_map key-folding miscompile note applies here too)
+    # (the shard_map key-folding note in streaming.fused_sw_sharded)
     study_keys = jax.vmap(lambda s: jax.random.fold_in(key, s))(study_idx)
     args = (args[0], args[1], args[2], study_keys, args[3])
     if data_ways > 1:
@@ -679,8 +679,8 @@ def permanova_many(dms: Union[Array, Sequence[Array]],
                 pipeline_many's fused path). Study counts that do not
                 divide the axis are padded and sliced. Per-study PRNG
                 keys are folded by GLOBAL study index ONCE per dispatch
-                before any sharding (the jax 0.4.x shard_map key-folding
-                miscompile note in streaming.fused_sw_sharded), so
+                before any sharding (the shard_map key-folding note in
+                streaming.fused_sw_sharded), so
                 sharded == single-host == S separate run() calls,
                 bit-identically, regardless of which shard runs a study.
     ordination: optional k — also compute top-k PCoA coordinates per
@@ -752,7 +752,8 @@ def permanova_many(dms: Union[Array, Sequence[Array]],
         args = tuple(jnp.take(a, wrap_idx, axis=0) for a in args)
         study_idx = wrap_idx
     # GLOBAL study index -> per-study key, folded ONCE here, before any
-    # sharding (never inside the sharded program: jax 0.4.x miscompile);
+    # sharding (never inside the sharded program, where it has
+    # miscompiled);
     # a padded slot replays its source study's key, so the pad is inert.
     study_keys = jax.vmap(lambda s: jax.random.fold_in(key, s))(study_idx)
     args = (args[0], args[1], study_keys, args[2])

@@ -53,6 +53,18 @@ def _warn_once(tag: str, msg: str) -> None:
     _WARNED.add(tag)
     _log.warning(msg)
 
+def record_candidate_failure(where: str, name: str,
+                             exc: BaseException) -> None:
+    """A shoot-out candidate that failed to lower or run: counted
+    (`autotune.candidate_failed`, and per candidate) and logged with its
+    exception. It leaves the shoot-out, but never in silence — on TPU a
+    kernel Mosaic refuses must not just vanish and let XLA win."""
+    _metrics.inc("autotune.candidate_failed")
+    _metrics.inc(f"autotune.candidate_failed.{name}")
+    _log.warning("%s: candidate %r failed and is excluded: %s: %s", where,
+                 name, type(exc).__name__, exc)
+
+
 # Model constants (bytes). LLC: an MI300A CCD carries 32 MiB L3; once mat2
 # spills it the paper's tiled dataflow wins on CPU.
 CPU_LLC_BYTES = 32 * 1024 ** 2
@@ -74,7 +86,12 @@ class Plan:
     def describe(self) -> str:
         t = ",".join(f"{k}={v}" for k, v in sorted(self.tuning.items()))
         mode = f"stream(chunk={self.chunk})" if self.streaming else "batch"
-        return f"{self.impl}[{t}] {mode} on {self.backend}: {self.reason}"
+        how = ""
+        if self.impl in registry.PALLAS_IMPLS:   # say how the kernel runs
+            how = (" Mosaic-compiled" if self.backend == "tpu"
+                   else " interpreted")
+        return (f"{self.impl}[{t}]{how} {mode} on {self.backend}: "
+                f"{self.reason}")
 
 
 def default_backend() -> str:
@@ -440,7 +457,8 @@ def autotune(mat2, grouping, inv_gs, *,
             t0 = time.perf_counter()
             jax.block_until_ready(fn(mat2, gperms, inv_gs))
             t = time.perf_counter() - t0
-        except Exception:  # noqa: BLE001 — an impl may not lower here
+        except Exception as exc:  # noqa: BLE001 — counted and logged
+            record_candidate_failure("autotune", name, exc)
             continue
         times_us[name] = round(t * 1e6, 1)
         if t < best_t:

@@ -18,10 +18,12 @@ Registered implementations:
 
   brute / tiled / matmul          pure-jnp forms from core.fstat
   pallas_brute / pallas_permblock / pallas_matmul
-                                  the Pallas TPU kernels (interpret mode off
-                                  TPU), via kernels.permanova_sw.ops
-  brute / matmul `.sharded`       row-sharded partials for shard_map
-                                  distribution (core.fstat.sw_*_rows_partial)
+                                  the Pallas TPU kernels (interpreted on the
+                                  CPU), via kernels.permanova_sw.ops
+  brute / matmul / pallas_matmul `.sharded`
+                                  row-sharded partials for shard_map
+                                  distribution (core.fstat.sw_*_rows_partial,
+                                  kernels.permanova_sw.ops)
 """
 
 from __future__ import annotations
@@ -118,8 +120,8 @@ def names(*, backend: Optional[str] = None, kind: Optional[str] = None):
 
 def get_sharded(name: str) -> Callable:
     """Row-sharded partial for `name`, falling back to the nearest family
-    member (tiled -> brute rows, pallas_* -> matmul rows) when the exact
-    impl has no shard-map companion."""
+    member (tiled, pallas_brute -> brute rows; pallas_permblock -> matmul
+    rows) when the exact impl has no shard-map companion."""
     impl = get(name)
     if impl.sharded is not None:
         return impl.sharded
@@ -171,6 +173,11 @@ def _make_pallas(variant):
     return make
 
 
+def _pallas_matmul_rows_partial(*args, **kw):
+    from repro.kernels.permanova_sw import ops  # deferred: pallas import
+    return ops.sw_matmul_rows_partial(*args, **kw)
+
+
 register(SwImpl(
     name="brute", kind="jnp", make=_make_jnp(fstat.sw_brute),
     backends=("gpu",), tuning={"block": 32}, pad_contract="none",
@@ -215,4 +222,5 @@ register(SwImpl(
     pad_contract="internal",
     description="Pallas MXU one-hot contraction (highest arithmetic "
                 "intensity; past the v5e ridge for perm_block*G >= ~512)",
+    sharded=_pallas_matmul_rows_partial,
 ))

@@ -17,6 +17,11 @@ VPU streaming kernel (|xi - xj| has no matmul form). Jaccard is the
 presence/absence matmul form: on 0/1 features the float product IS the set
 intersection, so |A ∩ B| accumulates on the MXU and |A ∪ B| falls out of
 the cardinality sums — every registered metric has a tiled stage-1 impl.
+
+The tile bodies are the fused megakernel's (kernels.common.accumulate):
+the row operand is sample-major (nr, d), the column operand feature-major
+(d, nc). Packed jaccard feeds int32 presence words through the same body
+(feat_block then counts words).
 """
 
 from __future__ import annotations
@@ -26,217 +31,51 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import common as _c
+
+METRICS = ("braycurtis", "euclidean", "jaccard")
 
 
-def _braycurtis_body(xr_ref, xc_ref, out_ref, num_ref, den_ref, *,
-                     n_feat_blocks):
+def _distance_body(xr_ref, xc_ref, out_ref, a_ref, b_ref, *, metric,
+                   feat_mode, n_feat_blocks):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
-        num_ref[...] = jnp.zeros_like(num_ref)
-        den_ref[...] = jnp.zeros_like(den_ref)
+        a_ref[...] = jnp.zeros_like(a_ref)
+        b_ref[...] = jnp.zeros_like(b_ref)
 
-    xr = xr_ref[...]                                # (TR, FB)
-    xc = xc_ref[...]                                # (TC, FB)
-    diff = jnp.abs(xr[:, None, :] - xc[None, :, :])
-    summ = xr[:, None, :] + xc[None, :, :]
-    num_ref[...] += jnp.sum(diff, axis=-1)
-    den_ref[...] += jnp.sum(summ, axis=-1)
+    _c.accumulate(metric, feat_mode, 1.0, xr_ref[...], xc_ref[...], a_ref,
+                  b_ref)
 
     @pl.when(k == n_feat_blocks - 1)
     def _finish():
-        out_ref[...] = num_ref[...] / jnp.maximum(den_ref[...], 1e-30)
+        out_ref[...] = _c.finalize_d(metric, a_ref[...], b_ref[...])
 
 
-def braycurtis_pallas(xr, xc, *, tile_r=128, tile_c=128, feat_block=128,
-                      interpret=True):
+def distance_pallas(xr, xc, *, metric, packed, tile_r, tile_c, feat_block,
+                    interpret):
+    """(nr, nc) distances of xr (nr, d) rows against xc (d, nc) columns,
+    both padded to tile multiples. packed=True: int32 presence words
+    (jaccard only)."""
     nr, d = xr.shape
-    nc = xc.shape[0]
+    nc = xc.shape[1]
     grid = (nr // tile_r, nc // tile_c, d // feat_block)
-    kernel = functools.partial(_braycurtis_body, n_feat_blocks=grid[2])
-    out, _, _ = pl.pallas_call(
+    kernel = functools.partial(
+        _distance_body, metric=metric,
+        feat_mode="packed" if packed else "dense", n_feat_blocks=grid[2])
+    return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((tile_r, feat_block), lambda i, j, k: (i, k)),
-            pl.BlockSpec((tile_c, feat_block), lambda i, j, k: (j, k)),
+            pl.BlockSpec((feat_block, tile_c), lambda i, j, k: (k, j)),
         ],
-        out_specs=[
-            pl.BlockSpec((tile_r, tile_c), lambda i, j, k: (i, j)),
-            pl.BlockSpec((tile_r, tile_c), lambda i, j, k: (i, j)),
-            pl.BlockSpec((tile_r, tile_c), lambda i, j, k: (i, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nr, nc), jnp.float32),  # distances
-            jax.ShapeDtypeStruct((nr, nc), jnp.float32),  # numerator accum
-            jax.ShapeDtypeStruct((nr, nc), jnp.float32),  # denominator accum
-        ],
+        out_specs=pl.BlockSpec((tile_r, tile_c), lambda i, j, k: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((nr, nc), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((tile_r, tile_c), jnp.float32),
+                        pltpu.VMEM((tile_r, tile_c), jnp.float32)],
         interpret=interpret,
     )(xr, xc)
-    return out
-
-
-def _jaccard_body(xr_ref, xc_ref, out_ref, inter_ref, card_ref, *,
-                  n_feat_blocks):
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        inter_ref[...] = jnp.zeros_like(inter_ref)
-        card_ref[...] = jnp.zeros_like(card_ref)
-
-    xr = xr_ref[...]                                # (TR, FB) presence 0/1
-    xc = xc_ref[...]                                # (TC, FB)
-    inter = jax.lax.dot_general(                    # MXU: |A ∩ B| per pair
-        xr, xc, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    inter_ref[...] += inter
-    card_ref[...] += (jnp.sum(xr, axis=-1)[:, None]
-                      + jnp.sum(xc, axis=-1)[None, :])
-
-    @pl.when(k == n_feat_blocks - 1)
-    def _finish():
-        inter = inter_ref[...]
-        union = card_ref[...] - inter               # |A ∪ B|
-        out_ref[...] = 1.0 - inter / jnp.maximum(union, 1.0)
-
-
-def jaccard_pallas(xr, xc, *, tile_r=128, tile_c=128, feat_block=128,
-                   interpret=True):
-    """xr/xc must be presence/absence floats (distance.presence_prepare)."""
-    nr, d = xr.shape
-    nc = xc.shape[0]
-    grid = (nr // tile_r, nc // tile_c, d // feat_block)
-    kernel = functools.partial(_jaccard_body, n_feat_blocks=grid[2])
-    out, _, _ = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile_r, feat_block), lambda i, j, k: (i, k)),
-            pl.BlockSpec((tile_c, feat_block), lambda i, j, k: (j, k)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tile_r, tile_c), lambda i, j, k: (i, j)),
-            pl.BlockSpec((tile_r, tile_c), lambda i, j, k: (i, j)),
-            pl.BlockSpec((tile_r, tile_c), lambda i, j, k: (i, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nr, nc), jnp.float32),  # distances
-            jax.ShapeDtypeStruct((nr, nc), jnp.float32),  # intersection accum
-            jax.ShapeDtypeStruct((nr, nc), jnp.float32),  # cardinality accum
-        ],
-        interpret=interpret,
-    )(xr, xc)
-    return out
-
-
-def _jaccard_packed_body(xr_ref, xc_ref, out_ref, inter_ref, card_ref, *,
-                         n_feat_blocks):
-    """Packed-bit jaccard tile: uint32 presence words, popcount forms.
-
-    The intersection/cardinality counts are exact integers (≤ d ≤ 2^24),
-    so their f32 accumulation is exact and the finalize arithmetic is
-    IDENTICAL to _jaccard_body's — the packed path is bit-identical to
-    the float matmul form while moving 32x fewer feature bytes."""
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        inter_ref[...] = jnp.zeros_like(inter_ref)
-        card_ref[...] = jnp.zeros_like(card_ref)
-
-    xr = xr_ref[...]                                # (TR, WB) uint32 words
-    xc = xc_ref[...]                                # (TC, WB)
-    inter = jnp.sum(                                # |A ∩ B| = popcount(AND)
-        jax.lax.population_count(xr[:, None, :] & xc[None, :, :]),
-        axis=-1).astype(jnp.float32)
-    inter_ref[...] += inter
-    card_r = jnp.sum(jax.lax.population_count(xr),
-                     axis=-1).astype(jnp.float32)
-    card_c = jnp.sum(jax.lax.population_count(xc),
-                     axis=-1).astype(jnp.float32)
-    card_ref[...] += card_r[:, None] + card_c[None, :]
-
-    @pl.when(k == n_feat_blocks - 1)
-    def _finish():
-        inter = inter_ref[...]
-        union = card_ref[...] - inter               # |A ∪ B|
-        out_ref[...] = 1.0 - inter / jnp.maximum(union, 1.0)
-
-
-def jaccard_packed_pallas(xr, xc, *, tile_r=128, tile_c=128, feat_block=128,
-                          interpret=True):
-    """xr/xc are (rows, words) uint32 packed presence slabs
-    (distance.pack_presence_bits); feat_block counts WORDS here."""
-    nr, d = xr.shape
-    nc = xc.shape[0]
-    grid = (nr // tile_r, nc // tile_c, d // feat_block)
-    kernel = functools.partial(_jaccard_packed_body, n_feat_blocks=grid[2])
-    out, _, _ = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile_r, feat_block), lambda i, j, k: (i, k)),
-            pl.BlockSpec((tile_c, feat_block), lambda i, j, k: (j, k)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tile_r, tile_c), lambda i, j, k: (i, j)),
-            pl.BlockSpec((tile_r, tile_c), lambda i, j, k: (i, j)),
-            pl.BlockSpec((tile_r, tile_c), lambda i, j, k: (i, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nr, nc), jnp.float32),  # distances
-            jax.ShapeDtypeStruct((nr, nc), jnp.float32),  # intersection accum
-            jax.ShapeDtypeStruct((nr, nc), jnp.float32),  # cardinality accum
-        ],
-        interpret=interpret,
-    )(xr, xc)
-    return out
-
-
-def _euclidean_body(xr_ref, xc_ref, out_ref, acc_ref, *, n_feat_blocks):
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    xr = xr_ref[...]
-    xc = xc_ref[...]
-    sq_r = jnp.sum(xr * xr, axis=-1)[:, None]
-    sq_c = jnp.sum(xc * xc, axis=-1)[None, :]
-    gram = jax.lax.dot_general(                     # MXU: (TR,FB)x(TC,FB)^T
-        xr, xc, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    acc_ref[...] += sq_r + sq_c - 2.0 * gram
-
-    @pl.when(k == n_feat_blocks - 1)
-    def _finish():
-        out_ref[...] = jnp.sqrt(jnp.maximum(acc_ref[...], 0.0))
-
-
-def euclidean_pallas(xr, xc, *, tile_r=128, tile_c=128, feat_block=128,
-                     interpret=True):
-    nr, d = xr.shape
-    nc = xc.shape[0]
-    grid = (nr // tile_r, nc // tile_c, d // feat_block)
-    kernel = functools.partial(_euclidean_body, n_feat_blocks=grid[2])
-    out, _ = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile_r, feat_block), lambda i, j, k: (i, k)),
-            pl.BlockSpec((tile_c, feat_block), lambda i, j, k: (j, k)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tile_r, tile_c), lambda i, j, k: (i, j)),
-            pl.BlockSpec((tile_r, tile_c), lambda i, j, k: (i, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nr, nc), jnp.float32),
-            jax.ShapeDtypeStruct((nr, nc), jnp.float32),
-        ],
-        interpret=interpret,
-    )(xr, xc)
-    return out

@@ -16,31 +16,44 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import distance as _dist
+from repro.kernels import common as _c
 from repro.kernels.distance import kernel as _k
 
-_KERNELS = {
-    "braycurtis": _k.braycurtis_pallas,
-    "euclidean": _k.euclidean_pallas,
-    "jaccard": _k.jaccard_pallas,
-}
-PALLAS_METRICS = tuple(_KERNELS)
+PALLAS_METRICS = _k.METRICS
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _pick(v: int, cap: int) -> int:
-    t = 1
-    while t * 2 <= min(v, cap):
-        t *= 2
-    return max(t, 8)
-
-
-def _check_packed(metric, packed):
+def _check(metric, packed):
+    if metric not in PALLAS_METRICS:
+        raise ValueError(f"unknown metric {metric!r}")
     if packed and metric != "jaccard":
         raise ValueError(
             f"packed=1 requires metric='jaccard' (got {metric!r})")
+
+
+def _rows(x_rows, x, *, metric, packed, tile_r, tile_c, feat_block,
+          interpret):
+    """Quantize, pad and launch; returns the padded distance slab."""
+    _check(metric, packed)
+    if packed:
+        def quant(v):
+            return jax.lax.bitcast_convert_type(
+                _dist.pack_presence_bits(v), jnp.int32)
+    else:
+        def quant(v):
+            return v.astype(jnp.float32)
+    xr_q, xc_q = quant(x_rows), quant(x)
+    b, d = xr_q.shape
+    n = xc_q.shape[0]
+    tile_r = _c.pick_tile(b, tile_r)
+    tile_c = _c.pick_tile(n, tile_c)
+    feat_block = _c.pick_tile(d, feat_block)
+    d_pad = _c.round_up(d, feat_block) - d
+    xr = jnp.pad(xr_q, ((0, _c.round_up(b, tile_r) - b), (0, d_pad)))
+    xc = jnp.pad(xc_q, ((0, _c.round_up(n, tile_c) - n), (0, d_pad))).T
+    return _k.distance_pallas(xr, xc, metric=metric, packed=bool(packed),
+                              tile_r=tile_r, tile_c=tile_c,
+                              feat_block=feat_block,
+                              interpret=_c.interpret_mode(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("metric", "tile_r", "tile_c",
@@ -55,33 +68,15 @@ def pairwise_distance(x, *, metric="braycurtis", tile_r=128, tile_c=128,
     metric (|0-0| = 0, zero presence bits intersect/union nothing; pad
     rows are sliced off). Jaccard expects presence/absence floats
     (distance.presence_prepare) — the registry's prepare supplies them.
-    packed=1 (jaccard only) packs presence into uint32 words and runs the
-    popcount tile body — bit-identical distances, 32x fewer feature bytes
+    packed=1 (jaccard only) packs presence into 32-bit words and runs the
+    popcount tile body — the same distances, 32x fewer feature bytes
     (feat_block then counts words).
     """
-    if interpret is None:
-        interpret = not _on_tpu()
-    if metric not in _KERNELS:
-        raise ValueError(f"unknown metric {metric!r}")
-    _check_packed(metric, packed)
     n = x.shape[0]
-    if packed:
-        xq = _dist.pack_presence_bits(x)
-        kern = _k.jaccard_packed_pallas
-    else:
-        xq = x.astype(jnp.float32)
-        kern = _KERNELS[metric]
-    d = xq.shape[1]
-    tile_r = _pick(n, tile_r)
-    tile_c = _pick(n, tile_c)
-    feat_block = _pick(d, feat_block)
-    n_pad = (-n) % max(tile_r, tile_c)
-    d_pad = (-d) % feat_block
-    xp = jnp.pad(xq, ((0, n_pad), (0, d_pad)))
-    out = kern(xp, xp, tile_r=tile_r, tile_c=tile_c,
-               feat_block=feat_block, interpret=interpret)
-    out = out[:n, :n]
-    return out * (1.0 - jnp.eye(n, dtype=out.dtype))  # exact zero diagonal
+    out = _rows(x, x, metric=metric, packed=packed, tile_r=tile_r,
+                tile_c=tile_c, feat_block=feat_block,
+                interpret=interpret)[:n, :n]
+    return _dist._zero_diag(out)                      # exact zero diagonal
 
 
 @functools.partial(jax.jit, static_argnames=("metric", "tile_r", "tile_c",
@@ -97,30 +92,6 @@ def pairwise_distance_rows(x_rows, x, *, metric="braycurtis", tile_r=128,
     (repro.pipeline.streaming does this while squaring into D²).
     packed=1: as in pairwise_distance (jaccard popcount word slabs).
     """
-    if interpret is None:
-        interpret = not _on_tpu()
-    if metric not in _KERNELS:
-        raise ValueError(f"unknown metric {metric!r}")
-    _check_packed(metric, packed)
-    b = x_rows.shape[0]
-    n = x.shape[0]
-    if packed:
-        xr_q = _dist.pack_presence_bits(x_rows)
-        xc_q = _dist.pack_presence_bits(x)
-        kern = _k.jaccard_packed_pallas
-    else:
-        xr_q = x_rows.astype(jnp.float32)
-        xc_q = x.astype(jnp.float32)
-        kern = _KERNELS[metric]
-    d = xr_q.shape[1]
-    tile_r = _pick(b, tile_r)
-    tile_c = _pick(n, tile_c)
-    feat_block = _pick(d, feat_block)
-    b_pad = (-b) % tile_r
-    n_pad = (-n) % tile_c
-    d_pad = (-d) % feat_block
-    xr = jnp.pad(xr_q, ((0, b_pad), (0, d_pad)))
-    xc = jnp.pad(xc_q, ((0, n_pad), (0, d_pad)))
-    out = kern(xr, xc, tile_r=tile_r, tile_c=tile_c,
-               feat_block=feat_block, interpret=interpret)
-    return out[:b, :n]
+    out = _rows(x_rows, x, metric=metric, packed=packed, tile_r=tile_r,
+                tile_c=tile_c, feat_block=feat_block, interpret=interpret)
+    return out[:x_rows.shape[0], :x.shape[0]]

@@ -18,8 +18,9 @@ phases per (i, j) tile pair:
                       pad rows/cols zeroed by GLOBAL index) and bank the
                       Gower row sums
   t in [nk, nk+npb)   permutation phase — contract the resident D² tile
-                      with one (perm_block, tile) label block per step on
-                      the MXU, accumulating s_W in a VMEM scratch vector
+                      with one permutation block per step on the MXU,
+                      accumulating the per-(perm, group) totals in a VMEM
+                      scratch slab
 
 Index maps clamp the out-of-phase block indices, so the feature operands
 simply stay resident during the permutation phase and vice versa. The s_W
@@ -46,87 +47,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import common as _c
+
 FUSED_METRICS = ("euclidean", "braycurtis", "jaccard")
-
-
 FEAT_MODES = ("dense", "fp8", "packed")
 
 
-def _accumulate(metric, feat_mode, scale, xr, xc, a_ref, b_ref):
-    """One feature block's contribution to the metric's running sums.
-
-    feat_mode selects the slab representation (static — each variant
-    traces its own body):
-
-      dense   f32 or bf16 slabs; MXU dot_generals consume them directly
-              with fp32 accumulation, elementwise paths cast up first
-      fp8     float8_e4m3fn slabs + one SMEM calibration scalar; tiles
-              are dequantized in-register (cast-up x scale) so the
-              running sums stay in real units with fp32 accumulation
-      packed  uint32 presence words (jaccard only); |A∩B| via
-              popcount(AND) and cardinalities via popcount row sums —
-              exact integer counts, bit-identical to the f32 matmul form
-
-    The accumulators are always fp32."""
-    if feat_mode == "packed":
-        if metric != "jaccard":  # pragma: no cover - ops validates
-            raise ValueError("packed slabs require the jaccard body")
-        inter = jnp.sum(
-            jax.lax.population_count(xr[:, None, :] & xc[None, :, :]),
-            axis=-1).astype(jnp.float32)
-        card_r = jnp.sum(jax.lax.population_count(xr),
-                         axis=-1).astype(jnp.float32)
-        card_c = jnp.sum(jax.lax.population_count(xc),
-                         axis=-1).astype(jnp.float32)
-        a_ref[...] += inter
-        b_ref[...] += card_r[:, None] + card_c[None, :]
-        return
-    if feat_mode == "fp8":
-        xr = xr.astype(jnp.float32) * scale
-        xc = xc.astype(jnp.float32) * scale
-    xr32 = xr if xr.dtype == jnp.float32 else xr.astype(jnp.float32)
-    xc32 = xc if xc.dtype == jnp.float32 else xc.astype(jnp.float32)
-    if metric == "euclidean":
-        sq_r = jnp.sum(xr32 * xr32, axis=-1)[:, None]
-        sq_c = jnp.sum(xc32 * xc32, axis=-1)[None, :]
-        gram = jax.lax.dot_general(                # MXU: (TR,FB)x(TC,FB)^T
-            xr, xc, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        a_ref[...] += sq_r + sq_c - 2.0 * gram     # accumulator IS D²
-    elif metric == "braycurtis":
-        a_ref[...] += jnp.sum(jnp.abs(xr32[:, None, :] - xc32[None, :, :]),
-                              axis=-1)
-        b_ref[...] += jnp.sum(xr32[:, None, :] + xc32[None, :, :], axis=-1)
-    elif metric == "jaccard":
-        inter = jax.lax.dot_general(               # |A ∩ B| on the MXU
-            xr, xc, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        a_ref[...] += inter
-        b_ref[...] += (jnp.sum(xr32, axis=-1)[:, None]
-                       + jnp.sum(xc32, axis=-1)[None, :])
-    else:  # pragma: no cover - ops validates
-        raise ValueError(metric)
-
-
-def _finalize_d2(metric, a, b):
-    """Squared distance tile from the completed running sums."""
-    if metric == "euclidean":
-        return jnp.maximum(a, 0.0)
-    if metric == "braycurtis":
-        d = a / jnp.maximum(b, 1e-30)
-        return d * d
-    # jaccard: union = card_r + card_c - inter
-    d = 1.0 - a / jnp.maximum(b - a, 1.0)
-    return d * d
-
-
-def _fused_sw_body(off_ref, scale_ref, xr_ref, xc_ref, g_row_ref, g_col_ref,
-                   sqrtw_ref, o_sw_ref, o_rs_ref, a_ref, b_ref, d2_ref,
-                   sw_ref, *, metric, feat_mode, nk, npb, nti, ntj, tile_r,
-                   tile_c, n_valid, nr_valid, n_groups):
+def _fused_body(off_ref, scale_ref, xr_ref, xc_ref, opr_ref, opc_ref,
+                o_sw_ref, o_rs_ref, a_ref, b_ref, d2_ref, sw_ref, swr_ref, *,
+                metric, feat_mode, nk, npb, nti, ntj, tile_r, tile_c,
+                n_valid, nr_valid, expand):
     i = pl.program_id(0)
     j = pl.program_id(1)
     t = pl.program_id(2)
+    # Sharded row slabs can end with fully-dead tiles (every global row
+    # past n_valid): skip their feature accumulation and perm contraction.
+    # Finalize still runs — a/b are zero-initialized and the validity
+    # mask zeroes the whole tile, so the banked row sums stay 0.
+    row_live = off_ref[0, 0] + i * tile_r < n_valid
 
     @pl.when((i == 0) & (j == 0) & (t == 0))
     def _init_sw():
@@ -137,10 +75,14 @@ def _fused_sw_body(off_ref, scale_ref, xr_ref, xc_ref, g_row_ref, g_col_ref,
         a_ref[...] = jnp.zeros_like(a_ref)
         b_ref[...] = jnp.zeros_like(b_ref)
 
-    @pl.when(t < nk)
+    @pl.when((j == 0) & (t == 0))
+    def _init_row_sw():
+        swr_ref[...] = jnp.zeros_like(swr_ref)
+
+    @pl.when((t < nk) & row_live)
     def _feature_phase():
-        _accumulate(metric, feat_mode, scale_ref[0, 0], xr_ref[...],
-                    xc_ref[...], a_ref, b_ref)
+        _c.accumulate(metric, feat_mode, scale_ref[0, 0], xr_ref[...],
+                      xc_ref[...], a_ref, b_ref)
 
     @pl.when(t == nk - 1)
     def _finalize():
@@ -155,156 +97,7 @@ def _fused_sw_body(off_ref, scale_ref, xr_ref, xc_ref, g_row_ref, g_col_ref,
         # contraction and row sums below both consume the masked tile
         valid = ((rows_l < nr_valid) & (rows_g < n_valid)
                  & (cols_g < n_valid) & (rows_g != cols_g))
-        d2 = jnp.where(valid, _finalize_d2(metric, a_ref[...], b_ref[...]),
-                       0.0)
-        d2_ref[...] = d2
-        rs = jnp.sum(d2, axis=1, keepdims=True).T       # (1, TR)
-
-        @pl.when(j == 0)
-        def _rs_init():
-            o_rs_ref[...] = rs
-
-        @pl.when(j > 0)
-        def _rs_acc():
-            o_rs_ref[...] += rs
-
-    @pl.when(t >= nk)
-    def _perm_phase():
-        pb = t - nk
-        g_r = g_row_ref[...]                            # (PB, TR)
-        g_c = g_col_ref[...]                            # (PB, TC)
-        sqrt_w = sqrtw_ref[0, :]                        # (G,)
-        iota_g = jax.lax.broadcasted_iota(jnp.int32, (1, 1, n_groups), 2)
-        e_col = (g_c[:, :, None] == iota_g).astype(jnp.float32) * sqrt_w
-        e_row = (g_r[:, :, None] == iota_g).astype(jnp.float32) * sqrt_w
-        # MXU contraction: (PB,TC,G) x (TR,TC) -> (PB, G, TR)
-        y = jax.lax.dot_general(
-            e_col, d2_ref[...],
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        s = jnp.sum(y * jnp.transpose(e_row, (0, 2, 1)), axis=(1, 2))
-        sw_ref[pb, :] += 0.5 * s
-
-    @pl.when((i == nti - 1) & (j == ntj - 1) & (t == nk + npb - 1))
-    def _flush():
-        o_sw_ref[...] = sw_ref[...]
-
-
-def fused_sw_pallas(row_offset, xr, xc, g_rows, g_cols, sqrt_w, *,
-                    metric, n_valid, nr_valid, tile_r=128, tile_c=128,
-                    feat_block=128, perm_block=16, feat_mode="dense",
-                    feat_scale=None, interpret=True):
-    """Launch the megakernel over pre-padded operands.
-
-    row_offset: (1, 1) int32 — global index of xr's first row (traced OK).
-    xr:      (nr_pad, d_pad) prepared row-slab features (f32/bf16 dense,
-             float8_e4m3fn for feat_mode='fp8', uint32 words for 'packed').
-    xc:      (nc_pad, d_pad) prepared full feature table (same dtype).
-    g_rows:  (p_pad, nr_pad) int32 permuted labels at the slab's rows.
-    g_cols:  (p_pad, nc_pad) int32 permuted labels over all samples.
-    sqrt_w:  (1, G) f32 sqrt(inv_group_sizes).
-    feat_scale: (1, 1) f32 fp8 calibration scalar (ignored otherwise).
-    Returns (s_W (p_pad,) f32, row_sums (nr_pad,) f32) — pad entries zero.
-    """
-    if metric not in FUSED_METRICS:
-        raise ValueError(f"unknown fused metric {metric!r}; "
-                         f"one of {FUSED_METRICS}")
-    if feat_mode not in FEAT_MODES:
-        raise ValueError(f"unknown feat_mode {feat_mode!r}; "
-                         f"one of {FEAT_MODES}")
-    if feat_scale is None:
-        feat_scale = jnp.ones((1, 1), jnp.float32)
-    nr, d = xr.shape
-    nc = xc.shape[0]
-    p_pad = g_cols.shape[0]
-    n_groups = sqrt_w.shape[-1]
-    nti, ntj = nr // tile_r, nc // tile_c
-    nk, npb = d // feat_block, p_pad // perm_block
-    kernel = functools.partial(
-        _fused_sw_body, metric=metric, feat_mode=feat_mode, nk=nk, npb=npb,
-        nti=nti, ntj=ntj, tile_r=tile_r, tile_c=tile_c, n_valid=n_valid,
-        nr_valid=nr_valid, n_groups=n_groups)
-    out_sw, out_rs = pl.pallas_call(
-        kernel,
-        grid=(nti, ntj, nk + npb),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((tile_r, feat_block),
-                         lambda i, j, t: (i, jnp.minimum(t, nk - 1))),
-            pl.BlockSpec((tile_c, feat_block),
-                         lambda i, j, t: (j, jnp.minimum(t, nk - 1))),
-            pl.BlockSpec((perm_block, tile_r),
-                         lambda i, j, t: (jnp.clip(t - nk, 0, npb - 1), i)),
-            pl.BlockSpec((perm_block, tile_c),
-                         lambda i, j, t: (jnp.clip(t - nk, 0, npb - 1), j)),
-            pl.BlockSpec((1, n_groups), lambda i, j, t: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((npb, perm_block), lambda i, j, t: (0, 0)),
-            pl.BlockSpec((1, tile_r), lambda i, j, t: (0, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((npb, perm_block), jnp.float32),
-            jax.ShapeDtypeStruct((1, nr), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((tile_r, tile_c), jnp.float32),   # metric accum a
-            pltpu.VMEM((tile_r, tile_c), jnp.float32),   # metric accum b
-            pltpu.VMEM((tile_r, tile_c), jnp.float32),   # masked D² tile
-            pltpu.VMEM((npb, perm_block), jnp.float32),  # s_W accumulator
-        ],
-        interpret=interpret,
-    )(row_offset, feat_scale, xr, xc, g_rows, g_cols, sqrt_w)
-    return out_sw.reshape(-1), out_rs[0]
-
-
-# ---------------------------------------------------------------------------
-# Dense-design variant: the perm phase contracts PERMUTED BASIS blocks
-# (hat-matrix factor columns, core.design) instead of building one-hot
-# factors from labels. Feature phase, D² scratch residency and Gower row
-# sums are identical; the output keeps the per-column axis so the host can
-# slice per-term partial statistics.
-# ---------------------------------------------------------------------------
-
-def _fused_sw_cols_body(off_ref, scale_ref, xr_ref, xc_ref, vr_ref, vc_ref,
-                        o_sw_ref, o_rs_ref, a_ref, b_ref, d2_ref, sw_ref, *,
-                        metric, feat_mode, nk, npb, nti, ntj, tile_r, tile_c,
-                        n_valid, nr_valid, k_cols):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    t = pl.program_id(2)
-    # Sharded row slabs can end with fully-dead tiles (every global row
-    # past n_valid): skip their feature accumulation and perm contraction
-    # entirely. Finalize still runs — a/b are zero-initialized and the
-    # validity mask zeroes the whole tile, so the banked row sums stay 0.
-    row_live = off_ref[0, 0] + i * tile_r < n_valid
-
-    @pl.when((i == 0) & (j == 0) & (t == 0))
-    def _init_sw():
-        sw_ref[...] = jnp.zeros_like(sw_ref)
-
-    @pl.when(t == 0)
-    def _init_acc():
-        a_ref[...] = jnp.zeros_like(a_ref)
-        b_ref[...] = jnp.zeros_like(b_ref)
-
-    @pl.when((t < nk) & row_live)
-    def _feature_phase():
-        _accumulate(metric, feat_mode, scale_ref[0, 0], xr_ref[...],
-                    xc_ref[...], a_ref, b_ref)
-
-    @pl.when(t == nk - 1)
-    def _finalize():
-        row_off = off_ref[0, 0]
-        rows_l = i * tile_r + jax.lax.broadcasted_iota(
-            jnp.int32, (tile_r, tile_c), 0)
-        rows_g = row_off + rows_l
-        cols_g = j * tile_c + jax.lax.broadcasted_iota(
-            jnp.int32, (tile_r, tile_c), 1)
-        valid = ((rows_l < nr_valid) & (rows_g < n_valid)
-                 & (cols_g < n_valid) & (rows_g != cols_g))
-        d2 = jnp.where(valid, _finalize_d2(metric, a_ref[...], b_ref[...]),
+        d2 = jnp.where(valid, _c.finalize_d2(metric, a_ref[...], b_ref[...]),
                        0.0)
         d2_ref[...] = d2
         rs = jnp.sum(d2, axis=1, keepdims=True).T       # (1, TR)
@@ -319,34 +112,31 @@ def _fused_sw_cols_body(off_ref, scale_ref, xr_ref, xc_ref, vr_ref, vc_ref,
 
     @pl.when((t >= nk) & row_live)
     def _perm_phase():
-        pb = t - nk
-        v_r = vr_ref[...]                               # (PB, TR, K)
-        v_c = vc_ref[...]                               # (PB, TC, K)
-        # MXU contraction: (PB,TC,K) x (TR,TC) -> (PB, K, TR)
-        y = jax.lax.dot_general(
-            v_c, d2_ref[...],
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        s = jnp.sum(y * jnp.transpose(v_r, (0, 2, 1)), axis=2)   # (PB, K)
-        sw_ref[pb, :, :] += 0.5 * s
+        u_r = expand(opr_ref[...])                      # (W, TR)
+        u_c = expand(opc_ref[...])                      # (W, TC)
+        # MXU: (W, TC) x (TR, TC)^T -> (W, TR), then the row-side factor
+        y = _c.dot(u_c, d2_ref[...], _c.DOT_NT)
+        tot = jnp.sum(y * u_r, axis=1, keepdims=True).T  # (1, W)
+        swr_ref[pl.ds(t - nk, 1), :] += tot
+
+    # Two-level f32 sum: tile totals add into a per-row-tile partial,
+    # which adds into the study total once per row tile. One running sum
+    # over all nti * ntj tiles drifts by ~eps * sqrt(nti * ntj) / 6
+    # relative (4e-6 at n = 25145 with 128-wide tiles, enough to move a
+    # p-value); sums of ntj and then nti terms drift by ~sqrt(2) times
+    # the drift of one axis instead.
+    @pl.when((j == ntj - 1) & (t == nk + npb - 1))
+    def _row_sw():
+        sw_ref[...] += swr_ref[...]
 
     @pl.when((i == nti - 1) & (j == ntj - 1) & (t == nk + npb - 1))
     def _flush():
         o_sw_ref[...] = sw_ref[...]
 
 
-def fused_sw_cols_pallas(row_offset, xr, xc, v_rows, v_cols, *,
-                         metric, n_valid, nr_valid, tile_r=128, tile_c=128,
-                         feat_block=128, perm_block=16, feat_mode="dense",
-                         feat_scale=None, interpret=True):
-    """Launch the dense-design megakernel over pre-padded operands.
-
-    v_rows: (p_pad, nr_pad, K) f32 permuted basis rows at the slab's rows.
-    v_cols: (p_pad, nc_pad, K) f32 permuted basis over all samples.
-    feat_mode/feat_scale: slab precision, as in fused_sw_pallas.
-    Returns (s_cols (p_pad, K) f32 per-column partials, row_sums
-    (nr_pad,) f32) — pad entries zero (zero basis rows/cols contribute
-    exactly nothing, which is what keeps ragged studies bit-exact)."""
+def _launch(row_offset, xr, xc, opr, opc, *, metric, n_valid, nr_valid,
+            tile_r, tile_c, feat_block, perm_block, width, expand,
+            feat_mode, feat_scale, interpret):
     if metric not in FUSED_METRICS:
         raise ValueError(f"unknown fused metric {metric!r}; "
                          f"one of {FUSED_METRICS}")
@@ -356,15 +146,21 @@ def fused_sw_cols_pallas(row_offset, xr, xc, v_rows, v_cols, *,
     if feat_scale is None:
         feat_scale = jnp.ones((1, 1), jnp.float32)
     nr, d = xr.shape
-    nc = xc.shape[0]
-    p_pad = v_cols.shape[0]
-    k_cols = v_cols.shape[-1]
+    nc = xc.shape[1]
+    p_pad = opc.shape[0]
     nti, ntj = nr // tile_r, nc // tile_c
     nk, npb = d // feat_block, p_pad // perm_block
+    tail = (0,) * (opc.ndim - 2)
+    op_block = (perm_block,) + opc.shape[1:-1]
     kernel = functools.partial(
-        _fused_sw_cols_body, metric=metric, feat_mode=feat_mode, nk=nk,
-        npb=npb, nti=nti, ntj=ntj, tile_r=tile_r, tile_c=tile_c,
-        n_valid=n_valid, nr_valid=nr_valid, k_cols=k_cols)
+        _fused_body, metric=metric, feat_mode=feat_mode, nk=nk, npb=npb,
+        nti=nti, ntj=ntj, tile_r=tile_r, tile_c=tile_c, n_valid=n_valid,
+        nr_valid=nr_valid, expand=expand)
+
+    def op_map(axis_block):
+        return lambda i, j, t: ((jnp.clip(t - nk, 0, npb - 1),) + tail
+                                + (axis_block(i, j),))
+
     out_sw, out_rs = pl.pallas_call(
         kernel,
         grid=(nti, ntj, nk + npb),
@@ -373,30 +169,88 @@ def fused_sw_cols_pallas(row_offset, xr, xc, v_rows, v_cols, *,
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((tile_r, feat_block),
                          lambda i, j, t: (i, jnp.minimum(t, nk - 1))),
-            pl.BlockSpec((tile_c, feat_block),
-                         lambda i, j, t: (j, jnp.minimum(t, nk - 1))),
-            pl.BlockSpec((perm_block, tile_r, k_cols),
-                         lambda i, j, t: (jnp.clip(t - nk, 0, npb - 1),
-                                          i, 0)),
-            pl.BlockSpec((perm_block, tile_c, k_cols),
-                         lambda i, j, t: (jnp.clip(t - nk, 0, npb - 1),
-                                          j, 0)),
+            pl.BlockSpec((feat_block, tile_c),
+                         lambda i, j, t: (jnp.minimum(t, nk - 1), j)),
+            pl.BlockSpec(op_block + (tile_r,), op_map(lambda i, j: i)),
+            pl.BlockSpec(op_block + (tile_c,), op_map(lambda i, j: j)),
         ],
         out_specs=[
-            pl.BlockSpec((npb, perm_block, k_cols),
-                         lambda i, j, t: (0, 0, 0)),
+            pl.BlockSpec((npb, width), lambda i, j, t: (0, 0)),
             pl.BlockSpec((1, tile_r), lambda i, j, t: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((npb, perm_block, k_cols), jnp.float32),
+            jax.ShapeDtypeStruct((npb, width), jnp.float32),
             jax.ShapeDtypeStruct((1, nr), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((tile_r, tile_c), jnp.float32),   # metric accum a
             pltpu.VMEM((tile_r, tile_c), jnp.float32),   # metric accum b
             pltpu.VMEM((tile_r, tile_c), jnp.float32),   # masked D² tile
-            pltpu.VMEM((npb, perm_block, k_cols), jnp.float32),  # s_cols
+            pltpu.VMEM((npb, width), jnp.float32),       # contraction totals
+            pltpu.VMEM((npb, width), jnp.float32),       # row-tile partial
         ],
         interpret=interpret,
-    )(row_offset, feat_scale, xr, xc, v_rows, v_cols)
-    return out_sw.reshape(-1, k_cols), out_rs[0]
+    )(row_offset, feat_scale, xr, xc, opr, opc)
+    return out_sw, out_rs[0]
+
+
+def fused_sw_pallas(row_offset, xr, xc, g_rows, g_cols, *, n_groups_pad,
+                    metric, n_valid, nr_valid, tile_r, tile_c, feat_block,
+                    perm_block, feat_mode, feat_scale, interpret):
+    """Launch the megakernel over pre-padded operands.
+
+    row_offset: (1, 1) int32 — global index of xr's first row (traced OK).
+    xr:      (nr_pad, d_pad) prepared row-slab features (f32/bf16 dense,
+             float8_e4m3fn for feat_mode='fp8', int32 words for 'packed').
+    xc:      (d_pad, nc_pad) prepared full feature table, FEATURE-major.
+    g_rows:  (p_pad, nr_pad) int32 permuted labels at the slab's rows.
+    g_cols:  (p_pad, nc_pad) int32 permuted labels over all samples.
+    n_groups_pad: group count rounded up to a multiple of 8.
+    feat_scale: (1, 1) f32 fp8 calibration scalar (ignored otherwise).
+    Returns (totals (p_pad, n_groups_pad) f32 with
+    totals[p, k] = sum_ij D²[i, j] [g_p(i) = k][g_p(j) = k] over the slab's
+    rows, row_sums (nr_pad,) f32) — pad entries zero. s_W is
+    0.5 * totals @ inv_group_sizes."""
+    out, rs = _launch(
+        row_offset, xr, xc, g_rows, g_cols, metric=metric, n_valid=n_valid,
+        nr_valid=nr_valid, tile_r=tile_r, tile_c=tile_c,
+        feat_block=feat_block, perm_block=perm_block,
+        width=perm_block * n_groups_pad,
+        expand=functools.partial(_c.onehot_t, n_groups_pad=n_groups_pad),
+        feat_mode=feat_mode, feat_scale=feat_scale, interpret=interpret)
+    return out.reshape(-1, n_groups_pad), rs
+
+
+# ---------------------------------------------------------------------------
+# Dense-design variant: the perm phase contracts PERMUTED BASIS blocks
+# (hat-matrix factor columns, core.design) instead of building one-hot
+# factors from labels. Feature phase, D² scratch residency and Gower row
+# sums are identical; the output keeps the per-column axis so the host can
+# slice per-term partial statistics.
+# ---------------------------------------------------------------------------
+
+def fused_sw_cols_pallas(row_offset, xr, xc, v_rows, v_cols, *, metric,
+                         n_valid, nr_valid, tile_r, tile_c, feat_block,
+                         perm_block, feat_mode, feat_scale, interpret):
+    """Launch the dense-design megakernel over pre-padded operands.
+
+    v_rows: (p_pad, K, nr_pad) f32 permuted basis at the slab's rows,
+            column-major per permutation (K a multiple of 8).
+    v_cols: (p_pad, K, nc_pad) f32 permuted basis over all samples.
+    Other operands as in fused_sw_pallas.
+    Returns (totals (p_pad, K) f32 with totals[p, k] =
+    sum_ij D²[i, j] v_p[i, k] v_p[j, k], row_sums (nr_pad,) f32) — pad
+    entries zero (zero basis rows/cols contribute exactly nothing, which
+    is what keeps ragged studies exact)."""
+    k_cols = v_cols.shape[1]
+
+    def expand(v):                       # (PB, K, T) -> (PB * K, T)
+        return v.reshape(v.shape[0] * k_cols, v.shape[2])
+
+    out, rs = _launch(
+        row_offset, xr, xc, v_rows, v_cols, metric=metric, n_valid=n_valid,
+        nr_valid=nr_valid, tile_r=tile_r, tile_c=tile_c,
+        feat_block=feat_block, perm_block=perm_block,
+        width=perm_block * k_cols, expand=expand, feat_mode=feat_mode,
+        feat_scale=feat_scale, interpret=interpret)
+    return out.reshape(-1, k_cols), rs

@@ -15,22 +15,12 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import common as _c
 from repro.kernels.fused_sw import kernel as _k
 
 # aitchison is euclidean geometry over clr-prepared features
 KERNEL_METRIC = {"euclidean": "euclidean", "braycurtis": "braycurtis",
                  "jaccard": "jaccard", "aitchison": "euclidean"}
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _pick(v: int, cap: int) -> int:
-    t = 1
-    while t * 2 <= min(v, cap):
-        t *= 2
-    return max(t, 8)
 
 
 def _feature_mode(metric, feat_bf16, feat_fp8, feat_packed) -> str:
@@ -51,15 +41,16 @@ def _feature_mode(metric, feat_bf16, feat_fp8, feat_packed) -> str:
 def _quantize_slabs(x_rows, x, metric, mode, feat_bf16, feat_scale):
     """Represent the prepared slabs at the requested precision.
 
-    Returns (xr, xc, scale (1,1) f32). packed -> uint32 presence words
-    (feature axis becomes words); fp8 -> float8_e4m3fn at the calibration
+    Returns (xr, xc, scale (1,1) f32). packed -> presence words viewed as
+    int32 (feature axis becomes words); fp8 -> float8_e4m3fn at the calibration
     scale (computed from the FULL table when not supplied, so every row
     slab of one study quantizes identically); dense -> f32/bf16."""
     from repro.core import distance as _dist
     if mode == "packed":
-        return (_dist.pack_presence_bits(x_rows),
-                _dist.pack_presence_bits(x),
-                jnp.ones((1, 1), jnp.float32))
+        def words(v):
+            return jax.lax.bitcast_convert_type(
+                _dist.pack_presence_bits(v), jnp.int32)
+        return words(x_rows), words(x), jnp.ones((1, 1), jnp.float32)
     if mode == "fp8":
         s = (_dist.fp8_metric_scale(x, metric) if feat_scale is None
              else jnp.asarray(feat_scale, jnp.float32))
@@ -69,6 +60,39 @@ def _quantize_slabs(x_rows, x, metric, mode, feat_bf16, feat_scale):
         return xr, xc, s.reshape(1, 1)
     dt = jnp.bfloat16 if feat_bf16 else jnp.float32
     return x_rows.astype(dt), x.astype(dt), jnp.ones((1, 1), jnp.float32)
+
+
+def _slabs(x_rows, x, *, metric, mode, feat_bf16, feat_scale, tile_r,
+           tile_c, feat_block):
+    """Quantize and pad the feature slabs for the kernel's layout: the row
+    slab sample-major (nr_pad, d_pad), the column table feature-major
+    (d_pad, nc_pad). Tiles are Mosaic-legal: the cap, or one full-extent
+    block when the axis is shorter."""
+    xr, xc, scale = _quantize_slabs(x_rows, x, metric, mode, feat_bf16,
+                                    feat_scale)
+    nr, d = xr.shape                     # words when packed, else features
+    n = xc.shape[0]
+    tile_r = _c.pick_tile(nr, tile_r)
+    tile_c = _c.pick_tile(n, tile_c)
+    feat_block = _c.pick_tile(d, feat_block)
+    r_pad = _c.round_up(nr, tile_r) - nr
+    c_pad = _c.round_up(n, tile_c) - n
+    d_pad = _c.round_up(d, feat_block) - d
+    xr = jnp.pad(xr, ((0, r_pad), (0, d_pad)))
+    xc = jnp.pad(xc, ((0, c_pad), (0, d_pad))).T
+    return xr, xc, scale, tile_r, tile_c, feat_block, r_pad, c_pad
+
+
+def _pad_perms(ops_, p, perm_block):
+    """Pad every (P, ..., T) operand's permutation axis to the perm block
+    (edge mode: excess rows are sliced off). The block is the whole padded
+    axis or a multiple of 8, so Mosaic accepts it at any P."""
+    perm_block = min(perm_block, _c.round_up(p, 8))
+    p_pad = _c.round_up(p, perm_block) - p
+    if p_pad:
+        ops_ = [jnp.pad(o, ((0, p_pad),) + ((0, 0),) * (o.ndim - 1),
+                        mode="edge") for o in ops_]
+    return ops_, perm_block
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -97,9 +121,9 @@ def fused_sw_rows(x_rows, x, g_rows, g_cols, inv_gs, row_offset, *,
                  (max|x|/448, computed once during prepare or passed as
                  feat_scale) and dequantized in-register; fp32
                  accumulation; ~1e-2 rel tolerance on F.
-    feat_packed: 1 = packed uint32 presence words (jaccard only) —
-                 32x feature-traffic cut, popcount tile bodies,
-                 bit-identical results to the f32 matmul form.
+    feat_packed: 1 = packed presence words (jaccard only) — 32x
+                 feature-traffic cut, popcount tile bodies, the same
+                 results as the f32 matmul form.
     feat_scale:  optional traced f32 scalar pinning the fp8 calibration
                  (drivers compute it once per study, not per chunk).
 
@@ -108,41 +132,28 @@ def fused_sw_rows(x_rows, x, g_rows, g_cols, inv_gs, row_offset, *,
     """
     metric = KERNEL_METRIC.get(metric, metric)
     mode = _feature_mode(metric, feat_bf16, feat_fp8, feat_packed)
-    if interpret is None:
-        interpret = not _on_tpu()
     nr = x_rows.shape[0]
-    n = x.shape[0]
     p = g_cols.shape[0]
     if n_valid is None:
-        n_valid = n
-    xr, xc, scale = _quantize_slabs(x_rows, x, metric, mode, feat_bf16,
-                                    feat_scale)
-    d = xr.shape[1]                      # words when packed, else features
-    tile_r = _pick(nr, tile_r)
-    tile_c = _pick(n, tile_c)
-    feat_block = _pick(d, feat_block)
-    perm_block = min(perm_block, p)
-    r_pad = (-nr) % tile_r
-    c_pad = (-n) % tile_c
-    d_pad = (-d) % feat_block
-    p_pad = (-p) % perm_block
-    xr = jnp.pad(xr, ((0, r_pad), (0, d_pad)))
-    xc = jnp.pad(xc, ((0, c_pad), (0, d_pad)))
-    # pad labels with 0s (masked D² zeroes those tiles' contributions) and
-    # perms edge-mode (excess results sliced off)
-    gr = jnp.pad(g_rows, ((0, 0), (0, r_pad)))
-    gc = jnp.pad(g_cols, ((0, 0), (0, c_pad)))
-    if p_pad:
-        gr = jnp.pad(gr, ((0, p_pad), (0, 0)), mode="edge")
-        gc = jnp.pad(gc, ((0, p_pad), (0, 0)), mode="edge")
-    sqrt_w = jnp.sqrt(inv_gs.astype(jnp.float32)).reshape(1, -1)
+        n_valid = x.shape[0]
+    xr, xc, scale, tile_r, tile_c, feat_block, r_pad, c_pad = _slabs(
+        x_rows, x, metric=metric, mode=mode, feat_bf16=feat_bf16,
+        feat_scale=feat_scale, tile_r=tile_r, tile_c=tile_c,
+        feat_block=feat_block)
+    # pad labels with 0s (masked D² zeroes those tiles' contributions)
+    (gr, gc), perm_block = _pad_perms(
+        [jnp.pad(g_rows, ((0, 0), (0, r_pad))),
+         jnp.pad(g_cols, ((0, 0), (0, c_pad)))], p, perm_block)
+    n_groups = inv_gs.shape[0]
+    gp = _c.round_up(n_groups, 8)
+    w = jnp.pad(inv_gs.astype(jnp.float32), (0, gp - n_groups))
     off = jnp.asarray(row_offset, jnp.int32).reshape(1, 1)
-    sw, rs = _k.fused_sw_pallas(
-        off, xr, xc, gr, gc, sqrt_w, metric=metric, n_valid=int(n_valid),
-        nr_valid=nr, tile_r=tile_r, tile_c=tile_c, feat_block=feat_block,
-        perm_block=perm_block, feat_mode=mode, feat_scale=scale,
-        interpret=interpret)
-    return sw[:p], rs[:nr]
+    tot, rs = _k.fused_sw_pallas(
+        off, xr, xc, gr, gc, n_groups_pad=gp, metric=metric,
+        n_valid=int(n_valid), nr_valid=nr, tile_r=tile_r, tile_c=tile_c,
+        feat_block=feat_block, perm_block=perm_block, feat_mode=mode,
+        feat_scale=scale, interpret=_c.interpret_mode(interpret))
+    return 0.5 * jnp.sum(tot[:p] * w, axis=1), rs[:nr]
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -165,43 +176,31 @@ def fused_sw_rows_cols(x_rows, x, v_rows, v_cols, row_offset, *,
     knobs, as documented on fused_sw_rows.
     Returns (s_cols (P, K) f32, row_sums (nr,) f32); summing partials
     over disjoint row slabs reconstructs the global per-column statistic.
-    K is padded to a multiple of 8 lanes internally — zero basis columns
+    K is padded to a multiple of 8 internally — zero basis columns
     contribute exactly zero and are sliced off.
     """
     metric = KERNEL_METRIC.get(metric, metric)
     mode = _feature_mode(metric, feat_bf16, feat_fp8, feat_packed)
-    if interpret is None:
-        interpret = not _on_tpu()
     nr = x_rows.shape[0]
-    n = x.shape[0]
     p, _, k = v_cols.shape
     if n_valid is None:
-        n_valid = n
-    xr, xc, scale = _quantize_slabs(x_rows, x, metric, mode, feat_bf16,
-                                    feat_scale)
-    d = xr.shape[1]                      # words when packed, else features
-    tile_r = _pick(nr, tile_r)
-    tile_c = _pick(n, tile_c)
-    feat_block = _pick(d, feat_block)
-    perm_block = min(perm_block, p)
-    r_pad = (-nr) % tile_r
-    c_pad = (-n) % tile_c
-    d_pad = (-d) % feat_block
-    p_pad = (-p) % perm_block
-    k_pad = (-k) % 8
-    xr = jnp.pad(xr, ((0, r_pad), (0, d_pad)))
-    xc = jnp.pad(xc, ((0, c_pad), (0, d_pad)))
-    vr = jnp.pad(v_rows.astype(jnp.float32),
-                 ((0, 0), (0, r_pad), (0, k_pad)))
-    vc = jnp.pad(v_cols.astype(jnp.float32),
-                 ((0, 0), (0, c_pad), (0, k_pad)))
-    if p_pad:
-        vr = jnp.pad(vr, ((0, p_pad), (0, 0), (0, 0)), mode="edge")
-        vc = jnp.pad(vc, ((0, p_pad), (0, 0), (0, 0)), mode="edge")
+        n_valid = x.shape[0]
+    xr, xc, scale, tile_r, tile_c, feat_block, r_pad, c_pad = _slabs(
+        x_rows, x, metric=metric, mode=mode, feat_bf16=feat_bf16,
+        feat_scale=feat_scale, tile_r=tile_r, tile_c=tile_c,
+        feat_block=feat_block)
+    k_pad = _c.round_up(k, 8) - k
+
+    def basis(v, pad):                   # (P, T, K) -> (P, K_pad, T_pad)
+        v = jnp.pad(v.astype(jnp.float32), ((0, 0), (0, pad), (0, k_pad)))
+        return jnp.swapaxes(v, 1, 2)
+
+    (vr, vc), perm_block = _pad_perms(
+        [basis(v_rows, r_pad), basis(v_cols, c_pad)], p, perm_block)
     off = jnp.asarray(row_offset, jnp.int32).reshape(1, 1)
-    sc, rs = _k.fused_sw_cols_pallas(
+    tot, rs = _k.fused_sw_cols_pallas(
         off, xr, xc, vr, vc, metric=metric, n_valid=int(n_valid),
         nr_valid=nr, tile_r=tile_r, tile_c=tile_c, feat_block=feat_block,
         perm_block=perm_block, feat_mode=mode, feat_scale=scale,
-        interpret=interpret)
-    return sc[:p, :k], rs[:nr]
+        interpret=_c.interpret_mode(interpret))
+    return 0.5 * tot[:p, :k], rs[:nr]

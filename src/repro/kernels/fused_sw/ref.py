@@ -51,6 +51,8 @@ def fused_sw_ref(x_rows, x, g_rows, g_cols, inv_gs, row_offset, *,
     cols_g = jnp.arange(n)[None, :]
     valid = (rows_g < n_valid) & (cols_g < n_valid) & (rows_g != cols_g)
     m2 = jnp.where(valid, d * d, 0.0)
-    e = fstat.onehot_perm_factors(g_cols, inv_gs, m2.dtype)      # (P, n, G)
-    e_rows = fstat.onehot_perm_factors(g_rows, inv_gs, m2.dtype)
-    return fstat.sw_matmul_contract(m2, e, e_rows), jnp.sum(m2, axis=1)
+    n_groups = inv_gs.shape[0]
+    e = fstat.onehot_perm_factors(g_cols, n_groups, m2.dtype)    # (P, n, G)
+    e_rows = fstat.onehot_perm_factors(g_rows, n_groups, m2.dtype)
+    return (fstat.sw_matmul_contract(m2, e, e_rows, inv_gs),
+            jnp.sum(m2, axis=1))

@@ -32,7 +32,7 @@ def _stream_body(a_ref, b_ref, s_ref, o_ref, *, op: str):
         raise ValueError(op)
 
 
-def stream_pallas(a, b, s, *, op: str, block=65536, interpret=True):
+def stream_pallas(a, b, s, *, op: str, block: int, interpret: bool):
     (n,) = a.shape
     grid = (n // block,)
     kernel = functools.partial(_stream_body, op=op)

@@ -5,31 +5,18 @@ Multi-pod:   (2, 16, 16)   -> ("pod", "data", "model") = 512 chips
 
 Functions, never module-level constants — importing this module must not
 touch jax device state (the dry-run sets XLA_FLAGS before first jax init).
-
-jax 0.4.x compat: `jax.sharding.AxisType` (and `jax.make_mesh`'s
-`axis_types` kwarg) only exist on jax >= 0.5. Same pattern as the
-shard_map shim in core/distributed.py: feature-detect once, degrade to the
-plain mesh (every axis behaves as Auto there anyway).
 """
 
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType as _AxisType
-except ImportError:  # pragma: no cover - version-dependent
-    _AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def make_mesh(shape, axes) -> Mesh:
-    """Arbitrary mesh (tests, small hosts), Auto axis types where supported."""
+    """Arbitrary mesh (tests, small hosts) with Auto axis types."""
     shape, axes = tuple(shape), tuple(axes)
-    if _AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(_AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

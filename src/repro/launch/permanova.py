@@ -31,7 +31,7 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro import engine, obs, pipeline
+from repro import compile_cache, engine, obs, pipeline
 from repro.core.distance import distance_matrix, validate_distance_matrix
 from repro.data.microbiome import synthetic_study
 
@@ -147,9 +147,10 @@ def main():
                          "implies the pipeline path")
     ap.add_argument("--kernel", action="store_true",
                     help="legacy alias: maps brute/matmul to the Pallas "
-                         "kernel variant (interpret mode off TPU)")
+                         "kernel variant (interpreted on the CPU)")
     ap.add_argument("--distributed", action="store_true",
-                    help="shard over all local devices")
+                    help="row-shard the distance matrix over all local "
+                         "devices ('model' axis), built in place")
     ap.add_argument("--trace", default=None, metavar="PATH",
                     help="record trace spans across every execution layer "
                          "and write Chrome/Perfetto trace_event JSON to "
@@ -161,6 +162,7 @@ def main():
                          "plus compile/traffic counters")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.enable()
 
     if args.trace or args.metrics:
         obs.enable(trace=bool(args.trace) or args.metrics, metrics=True)
@@ -276,19 +278,26 @@ def main():
         return 0
 
     t0 = time.time()
-    dm = distance_matrix(jnp.asarray(x), args.metric)
-    checks = validate_distance_matrix(dm)
-    assert checks["ok"], checks
+    if args.distributed:
+        # rows of D over every local device: the matrix is built already
+        # sharded, so a D larger than one chip never sits on device 0
+        from repro.core import distributed
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh(model_ways=len(jax.devices()))
+        dm = distributed.distance_matrix_sharded(mesh, jnp.asarray(x),
+                                                 args.metric)
+    else:
+        dm = distance_matrix(jnp.asarray(x), args.metric)
+        checks = validate_distance_matrix(dm)
+        assert checks["ok"], checks
+    jax.block_until_ready(dm)
     t_dm = time.time() - t0
 
     t0 = time.time()
     if args.distributed:
-        from repro.core import permanova_distributed
-        from repro.launch.mesh import make_host_mesh
-        mesh = make_host_mesh()
-        res = permanova_distributed(mesh, dm, jnp.asarray(grouping),
-                                    n_perms=args.perms, impl=impl,
-                                    key=jax.random.key(args.seed))
+        res = distributed.permanova_distributed(
+            mesh, dm, jnp.asarray(grouping), n_perms=args.perms, impl=impl,
+            key=jax.random.key(args.seed))
     else:
         res = engine.run(dm, jnp.asarray(grouping), n_perms=args.perms,
                          impl=impl, key=jax.random.key(args.seed),

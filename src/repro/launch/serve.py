@@ -20,7 +20,7 @@ import time
 import jax
 import numpy as np
 
-from repro import obs
+from repro import compile_cache, obs
 
 
 def _lm_args(ap: argparse.ArgumentParser) -> None:
@@ -204,6 +204,7 @@ def main(argv=None) -> int:
     _pa_args(sub.add_parser(
         "permanova", help="always-on PERMANOVA service smoke"))
     args = ap.parse_args(argv)
+    compile_cache.enable()
     return {"lm": cmd_lm, "permanova": cmd_permanova}[args.cmd](args)
 
 

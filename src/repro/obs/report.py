@@ -5,9 +5,8 @@ per-impl mat2 traffic) predict how many bytes each stage should move;
 the span buffer records how long each stage actually took. `report()`
 pairs the two — predicted bytes / measured wall-time = achieved GB/s —
 and flags stages whose achieved bandwidth falls below a configurable
-fraction of a reference bandwidth (the paper's MI300A STREAM-triad
-numbers, the v5e HBM roof on TPU, or $REPRO_OBS_PEAK_GBPS / the
-`peak_gbps=` argument). This is the measured counterpart of
+fraction of a reference bandwidth (the device's HBM peak from the
+repro.hw table, or $REPRO_OBS_PEAK_GBPS / the `peak_gbps=` argument). This is the measured counterpart of
 roofline/report.py's model-only tables, rendered through the same
 markdown table helper.
 """
@@ -51,33 +50,25 @@ def budget_violations(budgets: Dict[str, float]) -> list:
     return out
 
 
-def reference_gbps(backend: Optional[str] = None) -> float:
+def reference_gbps(device_kind: Optional[str] = None) -> float:
     """Reference bandwidth (GB/s) for the below-fraction flag: the env
-    override when set, else the paper's number for the backend family."""
+    override when set, else the HBM peak of the device (repro.hw table,
+    keyed by device_kind; an unknown device raises)."""
     override = os.environ.get(PEAK_GBPS_ENV)
     if override:
         return float(override)
     from repro import hw
-    if backend is None:
-        try:
-            import jax
-            backend = jax.default_backend()
-        except Exception:
-            backend = "cpu"
-    if backend == "tpu":
-        return hw.TPU_V5E.hbm_bandwidth / 1e9
-    if backend == "gpu":
-        return hw.MI300A_GPU_STREAM_TRIAD / 1e9
-    return hw.MI300A_CPU_STREAM_TRIAD / 1e9
+    return hw.device_spec(device_kind).hbm_bandwidth / 1e9
 
 
 def stage_rows(*, peak_gbps: Optional[float] = None,
                flag_fraction: float = 0.5,
-               backend: Optional[str] = None) -> list:
+               device_kind: Optional[str] = None) -> list:
     """One dict per span name carrying a predicted-bytes attr: predicted
     MiB, measured seconds, achieved GB/s, fraction of the reference, and
     the below-fraction flag. Sorted by measured time, slowest first."""
-    ref = peak_gbps if peak_gbps is not None else reference_gbps(backend)
+    ref = (peak_gbps if peak_gbps is not None
+           else reference_gbps(device_kind))
     rows = []
     for name, agg in _trace.stage_table().items():
         if agg["predicted_bytes"] <= 0.0:
@@ -99,7 +90,7 @@ def stage_rows(*, peak_gbps: Optional[float] = None,
 
 
 def report(*, peak_gbps: Optional[float] = None, flag_fraction: float = 0.5,
-           backend: Optional[str] = None,
+           device_kind: Optional[str] = None,
            budgets: Optional[Dict[str, float]] = None,
            file=sys.stdout) -> str:
     """Render (and print, unless file=None) the per-stage
@@ -109,9 +100,9 @@ def report(*, peak_gbps: Optional[float] = None, flag_fraction: float = 0.5,
     seconds, see budget_violations) — appends a budget-status section,
     flagging every entry over its limit."""
     from repro.roofline.report import render_table
-    ref = peak_gbps if peak_gbps is not None else reference_gbps(backend)
-    rows = stage_rows(peak_gbps=ref, flag_fraction=flag_fraction,
-                      backend=backend)
+    ref = (peak_gbps if peak_gbps is not None
+           else reference_gbps(device_kind))
+    rows = stage_rows(peak_gbps=ref, flag_fraction=flag_fraction)
     lines = [f"predicted-vs-measured per stage "
              f"(reference {ref:.1f} GB/s, flag below "
              f"{flag_fraction:.0%} of it):"]

@@ -122,8 +122,9 @@ def pipeline(x: Array, grouping: Array, *, metric: str = "braycurtis",
                  table stays on DISK and the planner grades its residency
                  tier against device_budget_bytes; below 'hbm' the sweep
                  runs out of core (async double-buffered slab prefetch
-                 into the fused contraction), F/p bit-identical to the
-                 in-memory bridges at the same slab boundaries.
+                 into the fused contraction), F/p equal to the in-memory
+                 bridges at the same slab boundaries up to f32 rounding
+                 of the distance tiles.
     materialize: 'auto' | 'dense' | 'stream' | 'fused' | 'fused-kernel' —
                  whether the (n, n) matrix is built outright, streamed into
                  a single buffer, never materialized at all, or (fused-
@@ -410,8 +411,10 @@ def _pipeline_ooc(cache: "_slabcache.SlabCache", grouping, *, metric: str,
     loads the cache once and reruns the ordinary in-memory path (same
     plan, same programs); 'host'/'disk' run the out-of-core sweep — the
     async prefetcher stages slab k+1 while slab k's distance tiles are
-    assembled and contracted by the UNCHANGED fused steps, so F/p are
-    bit-identical to the in-memory bridges at row_block == slab_rows.
+    assembled and contracted by the UNCHANGED fused steps, so F/p match
+    the in-memory bridges at row_block == slab_rows up to the f32
+    rounding of the tile-wise distance step (Gram-form metrics block
+    their feature sums by tile shape).
     """
     n, d = cache.n, cache.d
     n_total = n_perms + 1

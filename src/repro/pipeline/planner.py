@@ -94,13 +94,13 @@ class PipelinePlan:
         if self.residency != "hbm" and self.slab_rows and self.n:
             n_slabs = -(-self.n // self.slab_rows)
             traffic = _dreg.ooc_disk_traffic_bytes(n_slabs, self.disk_bytes)
-            gbps = _dreg.tier_bandwidth_gbps(self.residency, self.backend)
+            gbps = _dreg.tier_bandwidth_gbps(self.residency)
             lines.append(
                 f"residency: {self.residency} (features "
                 f"{4 * self.n * self.d / 2**20:.0f} MiB f32 exceed the "
                 f"device budget; {n_slabs} slabs x {self.slab_rows} rows)")
             lines.append("tier bandwidth model (GB/s): " + ", ".join(
-                f"{t}={_dreg.tier_bandwidth_gbps(t, self.backend):.1f}"
+                f"{t}={_dreg.tier_bandwidth_gbps(t):.1f}"
                 for t in _dreg.RESIDENCY_TIERS))
             lines.append(
                 f"predicted slab-cache traffic per sweep: "
@@ -482,7 +482,7 @@ def _fused_key(backend: str, metric: str, impl: str,
 def _stage1_candidates(metric: str, backend: str):
     names = _dreg.names(metric=metric, kind="dense") + \
         _dreg.names(metric=metric, kind="blocked")
-    if backend == "tpu":  # interpret-mode tiles are not a real candidate
+    if backend == "tpu":  # interpreted tiles are not a real candidate
         names += _dreg.names(metric=metric, kind="pallas")
     return names
 
@@ -539,7 +539,8 @@ def autotune_stage1(x, metric: str, *, backend: Optional[str] = None) -> str:
         _, _, dense_fn = spec.bound()
         try:
             t = _time_call(jax.jit(dense_fn), x)
-        except Exception:  # noqa: BLE001 — an impl may not lower here
+        except Exception as exc:  # noqa: BLE001 — counted and logged
+            _eplanner.record_candidate_failure("autotune_stage1", name, exc)
             continue
         _eplanner.record_entry(_stage1_key(backend, metric, name), {
             "impl": name, "us": round(t * 1e6, 1), "n": n, "d": d,
@@ -594,7 +595,8 @@ def autotune_fused(x, grouping, *, metric: str = "braycurtis",
             t0 = time.perf_counter()
             run()
             t = time.perf_counter() - t0
-        except Exception:  # noqa: BLE001
+        except Exception as exc:  # noqa: BLE001 — counted and logged
+            _eplanner.record_candidate_failure("autotune_fused", name, exc)
             continue
         _eplanner.record_entry(_fused_key(backend, metric, name, tuning), {
             "impl": name, "us": round(t * 1e6, 1), "n": n, "d": d,

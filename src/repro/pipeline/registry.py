@@ -11,7 +11,7 @@ Three kinds per metric (where available):
   blocked   row-streaming jnp driver over the same row primitives — the
             cache-friendly CPU form, and the only dense-free producer for
             the pipeline's stream/fused materializations
-  pallas    the tiled TPU kernels (interpret mode off TPU) — rectangular,
+  pallas    the tiled TPU kernels (interpreted on the CPU) — rectangular,
             so they serve both dense construction and row slabs
 
 Every impl exposes BOTH a dense builder and a row-slab builder (the dense
@@ -43,18 +43,17 @@ Array = object
 RESIDENCY_TIERS = ("vmem", "hbm", "host", "disk")
 
 # Model bandwidths (B/s). vmem: TPU-class on-chip SRAM order of magnitude;
-# hbm resolves per backend from the paper's measured numbers; host: DDR-class
-# staging the prefetcher reads through; disk: NVMe-class sequential read.
-# $REPRO_TIER_GBPS_<TIER> overrides any of them (GB/s).
+# hbm is the device's HBM peak (repro.hw, keyed by device_kind); host:
+# DDR-class staging the prefetcher reads through; disk: NVMe-class
+# sequential read. $REPRO_TIER_GBPS_<TIER> overrides any of them (GB/s).
 _VMEM_BPS = 22e12
 _HOST_BPS = 64e9
 _DISK_BPS = 2e9
 
 
-def tier_bandwidth_gbps(tier: str, backend: Optional[str] = None) -> float:
+def tier_bandwidth_gbps(tier: str, device_kind: Optional[str] = None) -> float:
     """Modelled bandwidth of one residency tier in GB/s ('hbm' is the
-    backend's device-memory roof: the paper's STREAM-triad numbers on
-    MI300A families, the v5e HBM roof on TPU)."""
+    device's HBM peak from the repro.hw table; an unknown device raises)."""
     if tier not in RESIDENCY_TIERS:
         raise ValueError(f"unknown residency tier {tier!r}; "
                          f"one of {RESIDENCY_TIERS}")
@@ -68,14 +67,7 @@ def tier_bandwidth_gbps(tier: str, backend: Optional[str] = None) -> float:
     if tier == "disk":
         return _DISK_BPS / 1e9
     from repro import hw
-    if backend is None:
-        import jax
-        backend = jax.default_backend()
-    if backend == "tpu":
-        return hw.TPU_V5E.hbm_bandwidth / 1e9
-    if backend == "gpu":
-        return hw.MI300A_GPU_STREAM_TRIAD / 1e9
-    return hw.MI300A_CPU_STREAM_TRIAD / 1e9
+    return hw.device_spec(device_kind).hbm_bandwidth / 1e9
 
 
 def residency_tier(feature_bytes: float, *, device_budget_bytes: float,
@@ -257,7 +249,7 @@ def _ws_rows_broadcast(n, d, block):
 
 
 def _ws_pallas(n, d, block):
-    # accumulators materialized at output size (interpret mode); tiles on TPU
+    # output slab plus padded feature copies (kept conservative)
     return 12 * min(block, n) * n
 
 
@@ -292,7 +284,7 @@ def _register_metric(metric, *, rows_ws, dense_ws, pallas_ok,
             make_dense=_make_pallas_dense(metric),
             workset_bytes=_ws_pallas,
             description=f"Pallas tiled {metric} kernel (VMEM-resident "
-                        "accumulators; interpret mode off TPU)",
+                        "accumulators; interpreted on the CPU)",
         ))
 
 

@@ -54,11 +54,6 @@ from repro import obs as _obs
 from repro.core import distance as _dist
 from repro.core import fstat, permutations
 
-try:  # jax >= 0.5 exposes shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 Array = jax.Array
 
 
@@ -190,11 +185,11 @@ def _fused_sw_step(m2rows, grouping, strata, inv_gs, key, lo_r, lo_p, *,
     else:
         g = permutations.strata_label_batch_dyn(key, grouping, strata,
                                                 lo_p, chunk)
-    e = fstat.onehot_perm_factors(g, inv_gs, m2rows.dtype)   # (P, n, G)
+    e = fstat.onehot_perm_factors(g, n_groups, m2rows.dtype)  # (P, n, G)
     e_pad = jnp.pad(e, ((0, 0), (0, (-n) % block), (0, 0)))
     e_rows = jax.lax.dynamic_slice(e_pad, (0, lo_r, 0),
                                    (chunk, block, n_groups))
-    return fstat.sw_matmul_contract(m2rows, e, e_rows)
+    return fstat.sw_matmul_contract(m2rows, e, e_rows, inv_gs)
 
 
 @functools.partial(jax.jit,
@@ -206,7 +201,7 @@ def _fused_sw_step_cols(m2rows, basis, strata, key, lo_r, lo_p, *,
     permutations gather basis rows; the per-column contraction returns a
     (chunk, K) partial over this row slab. `groups` (static, from
     fstat.sparse_col_groups) switches to the block-sparse gather form —
-    exact, because dropped terms are structural zeros."""
+    the dropped terms are structural zeros (equal to f32 rounding)."""
     perms = permutations.strata_permutation_batch_dyn(key, strata, lo_p,
                                                       chunk)
     v = fstat.basis_perm_factors(basis, perms)               # (P, n, K)
@@ -363,11 +358,11 @@ def _sweep_rows_perms(x_rows_pad, x_full, grouping, inv_gs, key,
             else:
                 g = permutations.strata_label_batch_dyn(
                     key, grouping, strata, lo_p, chunk)
-            e = fstat.onehot_perm_factors(g, inv_gs, m2.dtype)
+            e = fstat.onehot_perm_factors(g, n_groups, m2.dtype)
             e_pad = jnp.pad(e, ((0, 0), (0, n_rows_pad - n), (0, 0)))
             e_rows = jax.lax.dynamic_slice(
                 e_pad, (0, row_offset + lo_r, 0), (chunk, block, n_groups))
-            return None, fstat.sw_matmul_contract(m2, e, e_rows)
+            return None, fstat.sw_matmul_contract(m2, e, e_rows, inv_gs)
 
         _, sws = jax.lax.scan(chunk_body, None, chunk_los)
         rs = jax.lax.dynamic_update_slice(rs, jnp.sum(m2, axis=1), (lo_r,))
@@ -646,7 +641,7 @@ def fused_kernel_sw(xprep: Array, rows_fn: Callable, grouping: Array,
                     progress: Optional[Callable[[int, int], None]] = None):
     """Dispatch the single-pass fused sweep to the planned implementation.
 
-    impl: 'pallas' (the megakernel; interpret mode off TPU) or 'xla' (the
+    impl: 'pallas' (the megakernel; interpreted on the CPU) or 'xla' (the
     one-jit scan-of-scans). Both return (s_w (n_total,) float64, s_t,
     FusedKernelStats) with identical statistics for a fixed key.
     """
@@ -688,8 +683,8 @@ def fused_kernel_sw_design(xprep: Array, rows_fn: Callable, design,
 # slabs arrive through the async prefetcher (slab k+1 staged while slab k's
 # tiles contract), each (slab_rows, n) m2 row slab is assembled from
 # (slab, slab) distance tiles, and the UNCHANGED fused steps consume it —
-# so the statistic is bit-identical to the in-memory bridges at the same
-# slab boundaries by construction.
+# so the statistic matches the in-memory bridges at the same slab
+# boundaries up to the f32 rounding of the tile-wise distance step.
 # ---------------------------------------------------------------------------
 
 class OocStats(NamedTuple):
@@ -736,11 +731,11 @@ def _ooc_rowslab_onepass(m2rows, grouping, strata, inv_gs, key, lo_r, *,
         else:
             g = permutations.strata_label_batch_dyn(key, grouping, strata,
                                                     lo_p, chunk)
-        e = fstat.onehot_perm_factors(g, inv_gs, m2rows.dtype)
+        e = fstat.onehot_perm_factors(g, n_groups, m2rows.dtype)
         e_pad = jnp.pad(e, ((0, 0), (0, (-n) % block), (0, 0)))
         e_rows = jax.lax.dynamic_slice(e_pad, (0, lo_r, 0),
                                        (chunk, block, n_groups))
-        return None, fstat.sw_matmul_contract(m2rows, e, e_rows)
+        return None, fstat.sw_matmul_contract(m2rows, e, e_rows, inv_gs)
 
     _, sws = jax.lax.scan(chunk_body, None, chunk_los)
     return sws.reshape(-1)
@@ -806,8 +801,8 @@ def fused_sw_ooc(cache, rows_fn: Callable, prep_fn: Callable,
                  prefetch_depth: int = 2):
     """s_W with the feature table on DISK: slab-cache streaming into the
     fused contraction. onepass=False reuses `_fused_sw_step` verbatim (the
-    'fused' bridge out of core — bit-identical partial sums in the same
-    accumulation order as `fused_sw` at row_block == slab_rows);
+    'fused' bridge out of core — partial sums in the same accumulation
+    order as `fused_sw` at row_block == slab_rows);
     onepass=True runs one dispatch per row slab (the 'fused-kernel' form).
 
     Returns (s_w float64 (n_total,), s_t float, OocStats).
@@ -925,10 +920,10 @@ def fused_sw_sharded(mesh, xprep: Array, rows_fn: Callable, grouping: Array,
     The host drives one shard_map dispatch per permutation WINDOW of
     perm_ways * chunk global indices; inside it each shard generates its
     (chunk, n) label block with a single key-folding call. (Folding inside
-    a lax.scan over traced chunk offsets miscompiles under shard_map on
-    jax 0.4.x — the folded offsets silently collapse to the first shard's
-    when the labels feed a matmul — so the chunk loop stays at the host,
-    exactly like the megakernel driver.)
+    a lax.scan over traced chunk offsets has miscompiled under shard_map —
+    the folded offsets silently collapsing to the first shard's when the
+    labels feed a matmul — so the chunk loop stays at the host, exactly
+    like the megakernel driver.)
 
     Returns (s_w (n_total,) float64, s_t float, FusedKernelStats).
     """
@@ -958,7 +953,7 @@ def fused_sw_sharded(mesh, xprep: Array, rows_fn: Callable, grouping: Array,
             pidx = pidx * mesh.shape[a] + jax.lax.axis_index(a)
         lo = wlo[0] + pidx * chunk_local
         g = permutations.permutation_batch_dyn(k, grp, lo, chunk_local)
-        e = fstat.onehot_perm_factors(g, igs, jnp.float32)
+        e = fstat.onehot_perm_factors(g, n_groups, jnp.float32)
         e_pad = jnp.pad(e, ((0, 0), (0, n_rows_pad - n), (0, 0)))
 
         def slab_body(carry, lo_r):
@@ -974,16 +969,20 @@ def fused_sw_sharded(mesh, xprep: Array, rows_fn: Callable, grouping: Array,
                 (chunk_local, block, n_groups))
             rs = jax.lax.dynamic_update_slice(rs, jnp.sum(m2, axis=1),
                                               (lo_r,))
-            return (sw_acc + fstat.sw_matmul_contract(m2, e, e_rows),
+            return (sw_acc + fstat.sw_matmul_contract(m2, e, e_rows, igs),
                     rs), None
 
-        init = (jnp.zeros((chunk_local,), jnp.float32),
-                jnp.zeros((rows_per_shard,), jnp.float32))
+        # the carries vary across shards (partials per row slab and perm
+        # range); say so, as shard_map's type check requires of a scan
+        init = (jax.lax.pcast(jnp.zeros((chunk_local,), jnp.float32),
+                              tuple(mesh.axis_names), to="varying"),
+                jax.lax.pcast(jnp.zeros((rows_per_shard,), jnp.float32),
+                              ("model",), to="varying"))
         (s_w, rs), _ = jax.lax.scan(
             slab_body, init, jnp.arange(rows_per_shard // block) * block)
         return jax.lax.psum(s_w, axis_name="model"), rs
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P("model", None), P(), P(), P(), P(), P()),
         out_specs=(P(perm_axes), P("model")))

@@ -114,9 +114,7 @@ def analyze_compiled(compiled, *, chips: int,
     XLA's raw numbers are retained as diagnostics."""
     from repro.roofline.hlo_cost import loop_aware_cost
 
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax<=0.4.x: one dict per device
-        cost = cost[0] if cost else {}
+    cost = compiled.cost_analysis() or {}
     xla_flops = float(cost.get("flops", 0.0))
     xla_bytes = float(cost.get("bytes accessed", 0.0))
     hlo = compiled.as_text()

@@ -18,8 +18,11 @@ SRC = os.path.join(REPO, "src")
 
 
 def run_subprocess(code: str, *, devices: int = 8, timeout: int = 600):
-    """Run python code in a fresh interpreter with N fake devices."""
+    """Run python code in a fresh interpreter with N fake CPU devices.
+    The child is held to the CPU: it must never reach for a chip that the
+    test process (or another) may hold."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run([sys.executable, "-c", code], env=env,

@@ -78,3 +78,16 @@ def test_kernel_plugs_into_full_test(small_study):
     np.testing.assert_allclose(float(res_k.f_stat), float(res_ref.f_stat),
                                rtol=1e-4)
     assert float(res_k.p_value) == float(res_ref.p_value)
+
+
+def test_interpret_mode_only_on_cpu(monkeypatch):
+    from repro.kernels import common
+    assert common.interpret_mode() is True                # CPU backend
+    assert common.interpret_mode(False) is False          # described-TPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert common.interpret_mode() is False
+    with pytest.raises(ValueError, match="off on TPU"):
+        common.interpret_mode(True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError):
+        common.interpret_mode()

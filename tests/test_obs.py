@@ -320,3 +320,48 @@ class TestSpanRingBuffer:
             obs.disable()
             obs.clear()
             obs.set_buffer_cap(prev)
+
+
+class TestNoSilentFallback:
+    def test_failed_autotune_candidate_counted_and_logged(self, caplog,
+                                                          monkeypatch):
+        import jax.numpy as jnp
+        from repro.engine import planner, registry
+
+        def boom(**_):
+            def fn(mat2, groupings, inv_gs):
+                raise RuntimeError("refused by the compiler")
+            return fn
+
+        monkeypatch.setitem(registry._REGISTRY, "boom", registry.SwImpl(
+            name="boom", kind="jnp", make=boom, backends=(), tuning={},
+            pad_contract="none"))
+        x, g = _study(n=24, d=6, g=3)
+        dm = jnp.asarray(np.abs(x[:, None, 0] - x[None, :, 0]))
+        inv_gs = jnp.asarray([1.0 / np.sum(g == k) for k in range(3)],
+                             jnp.float32)
+        obs.enable(trace=False, metrics=True)
+        with caplog.at_level("WARNING", logger="repro.engine.planner"):
+            win = planner.autotune(dm * dm, jnp.asarray(g), inv_gs,
+                                   candidates=["matmul", "boom"],
+                                   sample_perms=4, use_cache=False)
+        assert win == "matmul"
+        assert obs.metrics.value("autotune.candidate_failed") == 1
+        assert obs.metrics.value("autotune.candidate_failed.boom") == 1
+        assert any("boom" in r.getMessage()
+                   and "refused by the compiler" in r.getMessage()
+                   for r in caplog.records)
+
+    def test_reference_bandwidth_keyed_by_device_kind(self):
+        from repro import hw
+        from repro.obs.report import reference_gbps
+        from repro.pipeline.registry import tier_bandwidth_gbps
+        assert reference_gbps("TPU v5 lite") == pytest.approx(819.0)
+        assert reference_gbps("cpu") == hw.HOST_CPU.hbm_bandwidth / 1e9
+        assert tier_bandwidth_gbps("hbm", "TPU v5 lite") == \
+            pytest.approx(819.0)
+        assert reference_gbps() == reference_gbps("cpu")   # this backend
+        for fn in (hw.device_spec, reference_gbps):
+            with pytest.raises(KeyError, match="no peak-rate entry"):
+                fn("TPU v99")
+        assert all(spec.source for spec in hw.DEVICES.values())

@@ -82,15 +82,11 @@ class TestCollectiveParsing:
         code = r"""
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.launch.mesh import make_mesh     # AxisType shim (jax 0.4.x)
+from repro.launch.mesh import make_mesh
 from repro.roofline.hlo_cost import loop_aware_cost
-try:  # jax >= 0.5 exposes shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
 mesh = make_mesh((8,), ("data",))
 def f(x):
-    return _shard_map(lambda y: jax.lax.psum(y, "data"), mesh=mesh,
+    return jax.shard_map(lambda y: jax.lax.psum(y, "data"), mesh=mesh,
                       in_specs=P("data"), out_specs=P())(x)
 xs = jax.ShapeDtypeStruct((1024,), jnp.float32)
 c = jax.jit(f).lower(xs).compile()

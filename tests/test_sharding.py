@@ -11,7 +11,7 @@ from repro.sharding.rules import (RULES_MULTI_POD, RULES_SINGLE_POD,
 
 
 def _mesh_1():
-    from repro.launch.mesh import make_mesh  # jax 0.4.x AxisType shim
+    from repro.launch.mesh import make_mesh
     return make_mesh((1, 1), ("data", "model"))
 
 
