@@ -57,6 +57,40 @@ def _sw_span_attrs(impl: str, n: int, n_total: int, chunk: int,
                                                  n_cols)}
 
 
+def _plan_labels(mat2, grouping, inv_gs, n_total: int, n_groups: int, *,
+                 impl: str, sw_fn: Optional[Callable], key: jax.Array,
+                 memory_budget_bytes: Optional[float], chunk: Optional[int],
+                 autotune: bool, backend: Optional[str],
+                 tuning: Optional[dict]):
+    """run()'s plan and bound s_W callable for a plain label design."""
+    n = mat2.shape[0]
+    if sw_fn is not None:
+        pl = planner.plan(n, n_total, n_groups, backend=backend,
+                          impl="matmul",  # footprint stand-in for budgeting
+                          memory_budget_bytes=memory_budget_bytes,
+                          chunk=chunk)
+        pl = dataclasses.replace(pl, impl="<custom sw_fn>",
+                                 reason="caller-supplied sw_fn")
+        return pl, sw_fn
+    pinned = None if impl == "auto" else impl
+    tuned = False
+    if autotune and pinned is not None:
+        warnings.warn(
+            f"autotune=True ignored: impl is pinned to {impl!r} "
+            "(use impl='auto' to let measurements pick)", stacklevel=3)
+    if pinned is None and autotune:
+        pinned = planner.autotune(mat2, grouping, inv_gs,
+                                  backend=backend, key=key)
+        tuned = True
+    pl = planner.plan(n, n_total, n_groups, backend=backend, impl=pinned,
+                      memory_budget_bytes=memory_budget_bytes,
+                      chunk=chunk, tuning=tuning)
+    if tuned:
+        pl = dataclasses.replace(
+            pl, reason="empirical autotune winner (measured on operands)")
+    return pl, registry.get(pl.impl).bound(**pl.tuning)
+
+
 def run(dm: Array, grouping: Array, *, n_perms: int = 999,
         key: Optional[jax.Array] = None, n_groups: Optional[int] = None,
         impl: str = "auto", sw_fn: Optional[Callable] = None,
@@ -89,15 +123,33 @@ def run(dm: Array, grouping: Array, *, n_perms: int = 999,
     """
     if key is None:
         key = jax.random.key(0)
-    if covariates is not None or strata is not None or weights is not None:
-        if isinstance(grouping, design_mod.Design):
-            raise ValueError("pass covariates/strata/weights either to "
-                             "run() or inside the Design, not both")
-        design = design_mod.build(
-            grouping=grouping, covariates=covariates, strata=strata,
-            weights=weights, n_groups=n_groups)
-    else:
-        design = design_mod.Design.from_labels(grouping, n_groups=n_groups)
+    # host phases of a test, each an obs span: engine.plan, engine.sw
+    # (its chunks split into dispatch and fetch), engine.finalize
+    with _obs.span("engine.plan"):
+        if (covariates is not None or strata is not None
+                or weights is not None):
+            if isinstance(grouping, design_mod.Design):
+                raise ValueError("pass covariates/strata/weights either to "
+                                 "run() or inside the Design, not both")
+            design = design_mod.build(
+                grouping=grouping, covariates=covariates, strata=strata,
+                weights=weights, n_groups=n_groups)
+        else:
+            design = design_mod.Design.from_labels(grouping,
+                                                   n_groups=n_groups)
+        if design.is_plain_labels:
+            dm = jnp.asarray(dm)
+            grouping = design.grouping
+            n = dm.shape[0]
+            n_groups = design.n_groups
+            mat2 = dm if squared else dm * dm
+            inv_gs = permutations.inv_group_sizes(grouping, n_groups)
+            n_total = n_perms + 1
+            pl, fn = _plan_labels(mat2, grouping, inv_gs, n_total, n_groups,
+                                  impl=impl, sw_fn=sw_fn, key=key,
+                                  memory_budget_bytes=memory_budget_bytes,
+                                  chunk=chunk, autotune=autotune,
+                                  backend=backend, tuning=tuning)
     if not design.is_plain_labels:
         if sw_fn is not None:
             raise ValueError("sw_fn is not supported with strata/covariate/"
@@ -106,57 +158,24 @@ def run(dm: Array, grouping: Array, *, n_perms: int = 999,
                           memory_budget_bytes=memory_budget_bytes,
                           chunk=chunk, autotune=autotune, backend=backend,
                           tuning=tuning, squared=squared, s_t=s_t)
-    dm = jnp.asarray(dm)
-    grouping = design.grouping
-    n = dm.shape[0]
-    n_groups = design.n_groups
-    mat2 = dm if squared else dm * dm
-    inv_gs = permutations.inv_group_sizes(grouping, n_groups)
-    n_total = n_perms + 1
-
-    if sw_fn is not None:
-        fn = sw_fn
-        pl = planner.plan(n, n_total, n_groups, backend=backend,
-                          impl="matmul",  # footprint stand-in for budgeting
-                          memory_budget_bytes=memory_budget_bytes,
-                          chunk=chunk)
-        pl = dataclasses.replace(pl, impl="<custom sw_fn>",
-                                 reason="caller-supplied sw_fn")
-    else:
-        pinned = None if impl == "auto" else impl
-        tuned = False
-        if autotune and pinned is not None:
-            warnings.warn(
-                f"autotune=True ignored: impl is pinned to {impl!r} "
-                "(use impl='auto' to let measurements pick)", stacklevel=2)
-        if pinned is None and autotune:
-            pinned = planner.autotune(mat2, grouping, inv_gs,
-                                      backend=backend, key=key)
-            tuned = True
-        pl = planner.plan(n, n_total, n_groups, backend=backend, impl=pinned,
-                          memory_budget_bytes=memory_budget_bytes,
-                          chunk=chunk, tuning=tuning)
-        if tuned:
-            pl = dataclasses.replace(
-                pl, reason="empirical autotune winner (measured on operands)")
-        fn = registry.get(pl.impl).bound(**pl.tuning)
 
     ch = pl.chunk if pl.streaming else n_total
     with _obs.span("engine.sw", _sw_span_attrs(pl.impl, n, n_total, ch)):
         if pl.streaming:
-            s_w_np, stats = scheduler.sw_streaming(
+            s_w_all, stats = scheduler.sw_streaming(
                 mat2, grouping, inv_gs, key, n_total, fn, chunk=pl.chunk)
-            s_w_all = jnp.asarray(s_w_np)
         else:
             s_w_all, stats = scheduler.sw_batch(
                 mat2, grouping, inv_gs, key, n_total, fn)
-    _obs.record_device_memory()
 
-    s_t = s_total(mat2) if s_t is None else jnp.float32(s_t)
-    f_all = f_from_sw(s_w_all, s_t, n, n_groups)
+    with _obs.span("engine.finalize"):
+        s_w_all = jnp.asarray(s_w_all)
+        s_t = s_total(mat2) if s_t is None else jnp.float32(s_t)
+        f_all = f_from_sw(s_w_all, s_t, n, n_groups)
+        p_value = p_value_from_null(f_all)
     return PermanovaResult(
         f_stat=f_all[0],
-        p_value=p_value_from_null(f_all),
+        p_value=p_value,
         s_t=s_t,
         s_w=s_w_all[0],
         f_perms=f_all,
@@ -294,7 +313,6 @@ def run_design(dm: Array, design: "design_mod.Design", *,
                 s_w_all, stats = scheduler.sw_batch(
                     mat2, grouping, inv_gs, key, n_total, fn,
                     strata=design.strata)
-        _obs.record_device_memory()
         s_t = s_total(mat2) if s_t is None else jnp.float32(s_t)
         return label_design_result(
             s_w_all, s_t, design, n_objects=n, n_perms=n_perms,
@@ -321,7 +339,6 @@ def run_design(dm: Array, design: "design_mod.Design", *,
         s_cols, stats = scheduler.sw_cols_streaming(
             mat2, design.basis, strata, key, n_total, cols_fn,
             chunk=pl.chunk)
-    _obs.record_device_memory()
     return design_result(
         s_cols, design, n_objects=n, n_perms=n_perms,
         method=f"permanova-design[{pl.impl}]",
@@ -603,7 +620,6 @@ def _permanova_many_design(dms, groupings, *, covariates, strata, weights,
     with _obs.span("engine.studies", attrs):
         s_cols = _obs.maybe_block(run_many(*args))[:s_count]  # (S, nt, K)
     _obs.metrics.inc("engine.studies", s_count)
-    _obs.record_device_memory()
 
     dof_resid = ((nv_i if n_valid is None else n_valid).astype(jnp.float32)
                  - jnp.float32(d0.rank))
@@ -770,7 +786,6 @@ def permanova_many(dms: Union[Array, Sequence[Array]],
     with _obs.span("engine.studies", attrs):
         f_perms, s_t, s_w = _obs.maybe_block(run_many(*args))
     _obs.metrics.inc("engine.studies", s_count)
-    _obs.record_device_memory()
     f_perms, s_t, s_w = (a[:s_count] for a in (f_perms, s_t, s_w))
     p_vals = jax.vmap(p_value_from_null)(f_perms)
 

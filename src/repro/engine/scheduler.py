@@ -10,6 +10,12 @@ independent of n_perms; results accumulate into a host-side float32 buffer
 
 One jitted step program serves every chunk (the start index is a traced
 scalar), so the sweep compiles once.
+
+Every step program generates its labels under `jax.named_scope(LABELS)`:
+the key folding, the shuffle's sorts, the `grouping[perm]` gather and the
+identity-first select carry `engine.labels` in their op_name metadata, so
+a device trace can sum the label layer apart from the s_W contraction. A
+scope adds metadata only; the compiled ops are the same.
 """
 
 from __future__ import annotations
@@ -26,6 +32,8 @@ from repro.core import permutations
 
 Array = jax.Array
 
+LABELS = "engine.labels"    # device scope of the permutation-label layer
+
 
 class StreamStats(NamedTuple):
     """Execution evidence for tests/telemetry: how the sweep actually ran."""
@@ -37,8 +45,9 @@ class StreamStats(NamedTuple):
 
 @functools.partial(jax.jit, static_argnames=("fn", "chunk", "identity_first"))
 def _step(mat2, grouping, inv_gs, key, lo, *, fn, chunk, identity_first):
-    gperms = permutations.permutation_batch_dyn(
-        key, grouping, lo, chunk, identity_first=identity_first)
+    with jax.named_scope(LABELS):
+        gperms = permutations.permutation_batch_dyn(
+            key, grouping, lo, chunk, identity_first=identity_first)
     return fn(mat2, gperms, inv_gs)
 
 
@@ -49,8 +58,9 @@ def _step_strata(mat2, grouping, strata, inv_gs, key, lo, *, fn, chunk,
     within-block index permutations; every label-based impl consumes them
     unchanged. A separate jitted program so the free-permutation path
     stays byte-identical to the pre-design repo."""
-    gperms = permutations.strata_label_batch_dyn(
-        key, grouping, strata, lo, chunk, identity_first=identity_first)
+    with jax.named_scope(LABELS):
+        gperms = permutations.strata_label_batch_dyn(
+            key, grouping, strata, lo, chunk, identity_first=identity_first)
     return fn(mat2, gperms, inv_gs)
 
 
@@ -60,8 +70,9 @@ def _step_cols(mat2, basis, strata, key, lo, *, fn, chunk, identity_first):
     constant strata vector is the free case) gather basis rows, and the
     per-column contraction returns (chunk, K)."""
     from repro.core import fstat
-    perms = permutations.strata_permutation_batch_dyn(
-        key, strata, lo, chunk, identity_first=identity_first)
+    with jax.named_scope(LABELS):
+        perms = permutations.strata_permutation_batch_dyn(
+            key, strata, lo, chunk, identity_first=identity_first)
     return fn(mat2, fstat.basis_perm_factors(basis, perms))
 
 
@@ -82,17 +93,19 @@ def _step_cols(mat2, basis, strata, key, lo, *, fn, chunk, identity_first):
 @functools.partial(jax.jit, static_argnames=("fn", "chunk", "identity_first"))
 def _step_masked(mat2, grouping, n_valid, inv_gs, key, lo, *, fn, chunk,
                  identity_first):
-    gperms = permutations.masked_permutation_batch_dyn(
-        key, grouping, n_valid, lo, chunk, identity_first=identity_first)
+    with jax.named_scope(LABELS):
+        gperms = permutations.masked_permutation_batch_dyn(
+            key, grouping, n_valid, lo, chunk, identity_first=identity_first)
     return fn(mat2, gperms, inv_gs)
 
 
 @functools.partial(jax.jit, static_argnames=("fn", "chunk", "identity_first"))
 def _step_masked_strata(mat2, grouping, strata, n_valid, inv_gs, key, lo, *,
                         fn, chunk, identity_first):
-    st = permutations.masked_strata(strata, n_valid)
-    gperms = permutations.strata_label_batch_dyn(
-        key, grouping, st, lo, chunk, identity_first=identity_first)
+    with jax.named_scope(LABELS):
+        st = permutations.masked_strata(strata, n_valid)
+        gperms = permutations.strata_label_batch_dyn(
+            key, grouping, st, lo, chunk, identity_first=identity_first)
     return fn(mat2, gperms, inv_gs)
 
 
@@ -100,9 +113,10 @@ def _step_masked_strata(mat2, grouping, strata, n_valid, inv_gs, key, lo, *,
 def _step_masked_cols(mat2, basis, strata, n_valid, key, lo, *, fn, chunk,
                       identity_first):
     from repro.core import fstat
-    st = permutations.masked_strata(strata, n_valid)
-    perms = permutations.strata_permutation_batch_dyn(
-        key, st, lo, chunk, identity_first=identity_first)
+    with jax.named_scope(LABELS):
+        st = permutations.masked_strata(strata, n_valid)
+        perms = permutations.strata_permutation_batch_dyn(
+            key, st, lo, chunk, identity_first=identity_first)
     return fn(mat2, fstat.basis_perm_factors(basis, perms))
 
 
@@ -118,8 +132,9 @@ def _step_masked_many(mat2, grouping, n_valid, inv_gs, key, lo, *, fn,
     result is bit-identical to an unbatched `_step_masked` call with that
     study's operands (asserted by the serve batched-vs-serial tests)."""
     def one(m2, g, nv, igs, k):
-        gperms = permutations.masked_permutation_batch_dyn(
-            k, g, nv, lo, chunk, identity_first=identity_first)
+        with jax.named_scope(LABELS):
+            gperms = permutations.masked_permutation_batch_dyn(
+                k, g, nv, lo, chunk, identity_first=identity_first)
         return fn(m2, gperms, igs)
     return jax.vmap(one)(mat2, grouping, n_valid, inv_gs, key)
 
@@ -128,9 +143,10 @@ def _step_masked_many(mat2, grouping, n_valid, inv_gs, key, lo, *, fn,
 def _step_masked_strata_many(mat2, grouping, strata, n_valid, inv_gs, key,
                              lo, *, fn, chunk, identity_first):
     def one(m2, g, st, nv, igs, k):
-        stm = permutations.masked_strata(st, nv)
-        gperms = permutations.strata_label_batch_dyn(
-            k, g, stm, lo, chunk, identity_first=identity_first)
+        with jax.named_scope(LABELS):
+            stm = permutations.masked_strata(st, nv)
+            gperms = permutations.strata_label_batch_dyn(
+                k, g, stm, lo, chunk, identity_first=identity_first)
         return fn(m2, gperms, igs)
     return jax.vmap(one)(mat2, grouping, strata, n_valid, inv_gs, key)
 
@@ -141,9 +157,10 @@ def _step_masked_cols_many(mat2, basis, strata, n_valid, key, lo, *, fn,
     from repro.core import fstat
 
     def one(m2, bs, st, nv, k):
-        stm = permutations.masked_strata(st, nv)
-        perms = permutations.strata_permutation_batch_dyn(
-            k, stm, lo, chunk, identity_first=identity_first)
+        with jax.named_scope(LABELS):
+            stm = permutations.masked_strata(st, nv)
+            perms = permutations.strata_permutation_batch_dyn(
+                k, stm, lo, chunk, identity_first=identity_first)
         return fn(m2, fstat.basis_perm_factors(bs, perms))
     return jax.vmap(one)(mat2, basis, strata, n_valid, key)
 
@@ -223,34 +240,37 @@ def sw_streaming(mat2: Array, grouping: Array, inv_gs: Array, key: jax.Array,
     out = np.empty((n_total,), np.float32)
     n_chunks = 0
     for lo in range(0, n_total, chunk):
-        with _obs.span("engine.sw_chunk", {"lo": lo}):
-            if strata is None:
-                s = _step(mat2, grouping, inv_gs, key, jnp.int32(lo),
-                          fn=fn, chunk=chunk, identity_first=identity_first)
-            else:
-                s = _step_strata(mat2, grouping, strata, inv_gs, key,
-                                 jnp.int32(lo), fn=fn, chunk=chunk,
-                                 identity_first=identity_first)
-            hi = min(lo + chunk, n_total)
-            # np.asarray is the device sync for this chunk — keep it inside
-            # the span so chunk wall-time covers completed device work
-            out[lo:hi] = np.asarray(s[: hi - lo])
+        hi = min(lo + chunk, n_total)
+        # spans take no attrs here: a disabled span site is one bool check
+        with _obs.span("engine.sw_chunk"):
+            with _obs.span("engine.dispatch"):
+                if strata is None:
+                    s = _step(mat2, grouping, inv_gs, key, jnp.int32(lo),
+                              fn=fn, chunk=chunk,
+                              identity_first=identity_first)
+                else:
+                    s = _step_strata(mat2, grouping, strata, inv_gs, key,
+                                     jnp.int32(lo), fn=fn, chunk=chunk,
+                                     identity_first=identity_first)
+                s = s[: hi - lo]
+            # np.asarray waits for the chunk and copies it back
+            with _obs.span("engine.fetch"):
+                out[lo:hi] = np.asarray(s)
         n_chunks += 1
         if progress is not None:
             progress(hi, n_total)
     stats = StreamStats(n_total=n_total, chunk=chunk, n_chunks=n_chunks,
                         peak_label_bytes=4 * chunk * n)
     _obs.metrics.inc("engine.perm_chunks", n_chunks)
-    _obs.metrics.gauge_set("engine.peak_label_bytes",
-                           stats.peak_label_bytes)
     return out, stats
 
 
 @functools.partial(jax.jit, static_argnames=("fn", "n_total",
                                              "identity_first"))
 def _batch_step(mat2, grouping, inv_gs, key, *, fn, n_total, identity_first):
-    gperms = permutations.permutation_batch(
-        key, grouping, 0, n_total, identity_first=identity_first)
+    with jax.named_scope(LABELS):
+        gperms = permutations.permutation_batch(
+            key, grouping, 0, n_total, identity_first=identity_first)
     return fn(mat2, gperms, inv_gs)
 
 
@@ -258,9 +278,10 @@ def _batch_step(mat2, grouping, inv_gs, key, *, fn, n_total, identity_first):
                                              "identity_first"))
 def _batch_step_strata(mat2, grouping, strata, inv_gs, key, *, fn, n_total,
                        identity_first):
-    gperms = permutations.strata_label_batch_dyn(
-        key, grouping, strata, jnp.int32(0), n_total,
-        identity_first=identity_first)
+    with jax.named_scope(LABELS):
+        gperms = permutations.strata_label_batch_dyn(
+            key, grouping, strata, jnp.int32(0), n_total,
+            identity_first=identity_first)
     return fn(mat2, gperms, inv_gs)
 
 
@@ -274,20 +295,22 @@ def sw_batch(mat2: Array, grouping: Array, inv_gs: Array, key: jax.Array,
     like the streaming `_step`. The previous eager form re-traced any
     scan inside the impl on EVERY call, so a warm serving process paid a
     fresh jaxpr trace per request — the obs retrace counter caught it."""
-    with _obs.span("engine.sw_chunk", {"lo": 0}):
-        if strata is None:
-            s_w = _batch_step(mat2, grouping, inv_gs, key, fn=fn,
-                              n_total=n_total, identity_first=identity_first)
-        else:
-            s_w = _batch_step_strata(
-                mat2, grouping, strata, inv_gs, key, fn=fn, n_total=n_total,
-                identity_first=identity_first)
-        s_w = _obs.maybe_block(s_w)
+    with _obs.span("engine.sw_chunk"):
+        with _obs.span("engine.dispatch"):
+            if strata is None:
+                s_w = _batch_step(mat2, grouping, inv_gs, key, fn=fn,
+                                  n_total=n_total,
+                                  identity_first=identity_first)
+            else:
+                s_w = _batch_step_strata(
+                    mat2, grouping, strata, inv_gs, key, fn=fn,
+                    n_total=n_total, identity_first=identity_first)
+        # the result stays on the device: wait for it only while tracing
+        with _obs.span("engine.fetch"):
+            s_w = _obs.maybe_block(s_w)
     stats = StreamStats(n_total=n_total, chunk=n_total, n_chunks=1,
                         peak_label_bytes=4 * n_total * int(mat2.shape[0]))
     _obs.metrics.inc("engine.perm_chunks", 1)
-    _obs.metrics.gauge_set("engine.peak_label_bytes",
-                           stats.peak_label_bytes)
     return s_w, stats
 
 
@@ -314,17 +337,19 @@ def sw_cols_streaming(mat2: Array, basis: Array, strata: Array,
     out = np.empty((n_total, k), np.float32)
     n_chunks = 0
     for lo in range(0, n_total, chunk):
-        with _obs.span("engine.sw_chunk", {"lo": lo, "cols": k}):
-            s = _step_cols(mat2, basis, strata, key, jnp.int32(lo),
-                           fn=fn, chunk=chunk, identity_first=identity_first)
-            hi = min(lo + chunk, n_total)
-            out[lo:hi] = np.asarray(s[: hi - lo])
+        hi = min(lo + chunk, n_total)
+        with _obs.span("engine.sw_chunk"):
+            with _obs.span("engine.dispatch"):
+                s = _step_cols(mat2, basis, strata, key, jnp.int32(lo),
+                               fn=fn, chunk=chunk,
+                               identity_first=identity_first)
+                s = s[: hi - lo]
+            with _obs.span("engine.fetch"):
+                out[lo:hi] = np.asarray(s)
         n_chunks += 1
         if progress is not None:
             progress(hi, n_total)
     stats = StreamStats(n_total=n_total, chunk=chunk, n_chunks=n_chunks,
                         peak_label_bytes=4 * chunk * n * (k + 1))
     _obs.metrics.inc("engine.perm_chunks", n_chunks)
-    _obs.metrics.gauge_set("engine.peak_label_bytes",
-                           stats.peak_label_bytes)
     return out, stats

@@ -36,6 +36,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import common as _c
 
 METRICS = ("braycurtis", "euclidean", "jaccard")
+NAME = "pairwise_distance"          # the custom call's HLO name
 
 
 def _distance_body(xr_ref, xc_ref, out_ref, a_ref, b_ref, *, metric,
@@ -78,4 +79,5 @@ def distance_pallas(xr, xc, *, metric, packed, tile_r, tile_c, feat_block,
         scratch_shapes=[pltpu.VMEM((tile_r, tile_c), jnp.float32),
                         pltpu.VMEM((tile_r, tile_c), jnp.float32)],
         interpret=interpret,
+        name=NAME,
     )(xr, xc)

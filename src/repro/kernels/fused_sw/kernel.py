@@ -52,6 +52,12 @@ from repro.kernels import common as _c
 FUSED_METRICS = ("euclidean", "braycurtis", "jaccard")
 FEAT_MODES = ("dense", "fp8", "packed")
 
+# The HLO names of the megakernel's custom calls, set by the pallas_call's
+# `name`; a device trace finds the kernel by them
+# (bench/metrics/fusedk_roofline.py).
+ROWS_NAME = "fused_sw_rows"
+ROWS_COLS_NAME = "fused_sw_rows_cols"
+
 
 def _fused_body(off_ref, scale_ref, xr_ref, xc_ref, opr_ref, opc_ref,
                 o_sw_ref, o_rs_ref, a_ref, b_ref, d2_ref, sw_ref, swr_ref, *,
@@ -136,7 +142,7 @@ def _fused_body(off_ref, scale_ref, xr_ref, xc_ref, opr_ref, opc_ref,
 
 def _launch(row_offset, xr, xc, opr, opc, *, metric, n_valid, nr_valid,
             tile_r, tile_c, feat_block, perm_block, width, expand,
-            feat_mode, feat_scale, interpret):
+            feat_mode, feat_scale, interpret, name):
     if metric not in FUSED_METRICS:
         raise ValueError(f"unknown fused metric {metric!r}; "
                          f"one of {FUSED_METRICS}")
@@ -190,6 +196,7 @@ def _launch(row_offset, xr, xc, opr, opc, *, metric, n_valid, nr_valid,
             pltpu.VMEM((npb, width), jnp.float32),       # row-tile partial
         ],
         interpret=interpret,
+        name=name,
     )(row_offset, feat_scale, xr, xc, opr, opc)
     return out_sw, out_rs[0]
 
@@ -217,7 +224,8 @@ def fused_sw_pallas(row_offset, xr, xc, g_rows, g_cols, *, n_groups_pad,
         feat_block=feat_block, perm_block=perm_block,
         width=perm_block * n_groups_pad,
         expand=functools.partial(_c.onehot_t, n_groups_pad=n_groups_pad),
-        feat_mode=feat_mode, feat_scale=feat_scale, interpret=interpret)
+        feat_mode=feat_mode, feat_scale=feat_scale, interpret=interpret,
+        name=ROWS_NAME)
     return out.reshape(-1, n_groups_pad), rs
 
 
@@ -252,5 +260,5 @@ def fused_sw_cols_pallas(row_offset, xr, xc, v_rows, v_cols, *, metric,
         nr_valid=nr_valid, tile_r=tile_r, tile_c=tile_c,
         feat_block=feat_block, perm_block=perm_block,
         width=perm_block * k_cols, expand=expand, feat_mode=feat_mode,
-        feat_scale=feat_scale, interpret=interpret)
+        feat_scale=feat_scale, interpret=interpret, name=ROWS_COLS_NAME)
     return out.reshape(-1, k_cols), rs

@@ -48,6 +48,11 @@ from repro.kernels import common as _c
 
 LANES = 128
 
+# The HLO names of the s_W custom calls, set by each pallas_call's `name`.
+# A device trace finds the kernels by them (bench/metrics/sw_roofline.py).
+SW_NAME = "permanova_sw"
+SW_ROWS_NAME = "sw_matmul_rows_partial"
+
 
 def _row_weights(w_ref, g, n_groups: int):
     """w[g] elementwise, w read from SMEM (G is small and static)."""
@@ -111,6 +116,7 @@ def sw_brute_pallas(mat2, groupings, w, *, tile_r, tile_c, interpret):
         out_specs=pl.BlockSpec((8, LANES), lambda p, i, j: (p // 8, 0)),
         out_shape=jax.ShapeDtypeStruct((n_perms, LANES), jnp.float32),
         interpret=interpret,
+        name=SW_NAME,
     )(w.reshape(1, -1), groupings, groupings, mat2)
 
 
@@ -168,6 +174,7 @@ def sw_permblock_pallas(mat2, groupings, w, *, perm_block, tile_r, tile_c,
         out_shape=jax.ShapeDtypeStruct((n_perms, LANES), jnp.float32),
         scratch_shapes=[pltpu.VMEM((perm_block, tile_c), jnp.float32)],
         interpret=interpret,
+        name=SW_NAME,
     )(w.reshape(1, -1), groupings, groupings, mat2)
 
 
@@ -196,7 +203,7 @@ def _sw_matmul_body(g_row_ref, g_col_ref, m2_ref, o_ref, acc_ref, *,
 
 
 def sw_matmul_pallas(mat2, g_rows, g_cols, *, n_groups_pad, perm_block,
-                     tile_r, tile_c, interpret):
+                     tile_r, tile_c, interpret, name):
     """Per-(perm, group) same-group sums over the (i != j) entries of mat2.
 
     mat2 (nr, nc) is the whole matrix or a slab of its rows; g_rows
@@ -204,7 +211,8 @@ def sw_matmul_pallas(mat2, g_rows, g_cols, *, n_groups_pad, perm_block,
     of all columns. mat2 may be bf16 (accumulation is always fp32).
     Returns (P, n_groups_pad) f32 totals: s_W is 0.5 * totals @
     inv_group_sizes (the zero diagonal makes the halved symmetric sum
-    exact; row slabs' totals add up to the whole matrix's)."""
+    exact; row slabs' totals add up to the whole matrix's). `name` is the
+    custom call's HLO name (SW_NAME or SW_ROWS_NAME)."""
     n_perms = g_cols.shape[0]
     nti, ntj = mat2.shape[0] // tile_r, mat2.shape[1] // tile_c
     npb = n_perms // perm_block
@@ -223,5 +231,6 @@ def sw_matmul_pallas(mat2, g_rows, g_cols, *, n_groups_pad, perm_block,
         out_shape=jax.ShapeDtypeStruct((npb, 1, width), jnp.float32),
         scratch_shapes=[pltpu.VMEM((width, tile_r), jnp.float32)],
         interpret=interpret,
+        name=name,
     )(g_rows, g_cols, mat2)
     return out.reshape(n_perms, n_groups_pad)

@@ -65,7 +65,8 @@ def permanova_sw(mat2, groupings, inv_group_sizes, *, variant="matmul",
     if variant == "matmul":
         return _matmul_sw(mat2, groupings, groupings, w,
                           perm_block=perm_block, tile_r=tile_r,
-                          tile_c=tile_c, interpret=interpret)
+                          tile_c=tile_c, interpret=interpret,
+                          name=_k.SW_NAME)
     n = mat2.shape[0]
     n_perms = groupings.shape[0]
     tile_r = _c.pick_tile(n, tile_r)
@@ -85,7 +86,7 @@ def permanova_sw(mat2, groupings, inv_group_sizes, *, variant="matmul",
 
 
 def _matmul_sw(mat2, g_rows, g_cols, w, *, perm_block, tile_r, tile_c,
-               interpret):
+               interpret, name):
     """s_W (P,) of mat2 (nr, nc) — the whole matrix or a slab of its rows
     — through sw_matmul_pallas. Pads rows, columns and permutations to
     the blocks (zero mat2 pad contributes nothing; pad permutations repeat
@@ -109,7 +110,8 @@ def _matmul_sw(mat2, g_rows, g_cols, w, *, perm_block, tile_r, tile_c,
     gp = _c.round_up(n_groups, 8)
     tot = _k.sw_matmul_pallas(mat2, g_rows, g_cols, n_groups_pad=gp,
                               perm_block=perm_block, tile_r=tile_r,
-                              tile_c=tile_c, interpret=interpret)
+                              tile_c=tile_c, interpret=interpret,
+                              name=name)
     w = jnp.pad(w, (0, gp - n_groups))
     return 0.5 * jnp.sum(tot[:n_perms] * w, axis=1)
 
@@ -132,7 +134,8 @@ def sw_matmul_rows_partial(mat2_rows, row_offset, groupings,
     return _matmul_sw(mat2_rows, g_rows, groupings,
                       inv_group_sizes.astype(jnp.float32),
                       perm_block=perm_block, tile_r=tile_r, tile_c=tile_c,
-                      interpret=_c.interpret_mode(interpret))
+                      interpret=_c.interpret_mode(interpret),
+                      name=_k.SW_ROWS_NAME)
 
 
 def make_sw_fn(variant: str = "matmul", **kw):

@@ -47,4 +47,5 @@ def stream_pallas(a, b, s, *, op: str, block: int, interpret: bool):
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n,), a.dtype),
         interpret=interpret,
+        name=f"stream_{op}",
     )(a, b, jnp.asarray([s], a.dtype))

@@ -4,9 +4,12 @@ Retraces are counted through `jax.monitoring`'s event-duration stream:
 every fresh jaxpr trace of a jitted function fires one
 `/jax/core/compile/jaxpr_trace_duration` event (warm cache hits fire
 none), and every backend compile fires
-`/jax/core/compile/backend_compile_duration`. The listener is installed
-once per process and is inert while metrics are disabled, so other
-listeners and the uninstrumented fast path are untouched.
+`/jax/core/compile/backend_compile_duration`. Each backend compile is
+also counted under the program that compiled, from the event's
+`fun_name` (`jax.backend_compiles.jit(_step)`), so a compile inside a
+timed window names its program. The listener is installed once per
+process and is inert while metrics are disabled, so other listeners and
+the uninstrumented fast path are untouched.
 """
 
 from __future__ import annotations
@@ -30,6 +33,9 @@ def _on_event_duration(event: str, duration: float, **kwargs) -> None:
         _metrics.observe("jax.trace_seconds", duration)
     elif event == BACKEND_COMPILE_EVENT:
         _metrics.inc(BACKEND_COMPILES)
+        fun_name = kwargs.get("fun_name")
+        if fun_name:
+            _metrics.inc(f"{BACKEND_COMPILES}.{fun_name}")
         _metrics.observe("jax.compile_seconds", duration)
 
 

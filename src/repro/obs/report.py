@@ -99,7 +99,10 @@ def report(*, peak_gbps: Optional[float] = None, flag_fraction: float = 0.5,
     budgets: optional SLO table (fnmatch span pattern -> max total
     seconds, see budget_violations) — appends a budget-status section,
     flagging every entry over its limit."""
+    from repro.obs import jaxhooks
     from repro.roofline.report import render_table
+    # peak device memory is read once here, not on every entry's call
+    jaxhooks.record_device_memory()
     ref = (peak_gbps if peak_gbps is not None
            else reference_gbps(device_kind))
     rows = stage_rows(peak_gbps=ref, flag_fraction=flag_fraction)

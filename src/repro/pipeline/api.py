@@ -357,7 +357,6 @@ def pipeline(x: Array, grouping: Array, *, metric: str = "braycurtis",
                     row_block=pl.row_block, chunk=pl.sw.chunk,
                     tuning=pl.fused_tuning)
             s_w = _obs.maybe_block(s_w)
-        _obs.record_device_memory()
         f_all = f_from_sw(jnp.asarray(s_w, jnp.float32),
                           jnp.float32(s_t), n, n_groups)
         res = PermanovaResult(
@@ -530,7 +529,6 @@ def _pipeline_ooc(cache: "_slabcache.SlabCache", grouping, *, metric: str,
             # evidence lands in the trace artifact
             span_attrs["stall_ms"] = round(ost.stall_s * 1e3, 3)
             span_attrs["disk_bytes_read"] = ost.disk_bytes_read
-    _obs.record_device_memory()
 
     sweep = (f"residency={pl.residency} slabs={ost.n_slabs}"
              f"x{ost.slab_rows} chunks={ost.n_chunks} "
@@ -938,7 +936,6 @@ def _pipeline_many_fused(xs: Array, groupings: Array, *, n_groups: int,
                                 studies=s_count)):
         s_w_all, rs = _obs.maybe_block(run(*args))  # (S', n_chunks*ch)
     _obs.metrics.inc("engine.studies", s_count)
-    _obs.record_device_memory()
     s_w_all = s_w_all[:s_count, :n_total]
     s_t = jnp.sum(rs[:s_count, :n], axis=1) / 2.0 / n
     f_perms = jax.vmap(f_from_sw, in_axes=(0, 0, None, None))(
@@ -1059,7 +1056,6 @@ def _pipeline_many_fused_design(xs: Array, groupings: Array, *,
                                 studies=s_count)):
         s_cols_all, rs = _obs.maybe_block(run(*args))  # (S', nc*ch, K)
     _obs.metrics.inc("engine.studies", s_count)
-    _obs.record_device_memory()
     s_cols = s_cols_all[:s_count, :n_total]
 
     ord_res = None

@@ -12,11 +12,22 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import obs, pipeline
+from repro import engine, obs, pipeline
 from repro.engine import planner as eplanner
 from repro.obs import jaxhooks
 
 N, D, G = 53, 24, 4
+
+# the host phases of engine.run, as its span sites name them
+ENGINE_SPANS = ("engine.plan", "engine.sw", "engine.sw_chunk",
+                "engine.dispatch", "engine.fetch", "engine.finalize")
+
+
+def _matrix_study(n=64, g=3):
+    rng = np.random.default_rng(1)
+    x = rng.random((n, 8))
+    dm = np.sqrt(((x[:, None] - x[None]) ** 2).sum(-1)).astype(np.float32)
+    return jnp.asarray(dm), jnp.asarray(np.arange(n) % g, jnp.int32)
 
 
 def _study(seed=0, n=N, d=D, g=G):
@@ -120,6 +131,16 @@ class TestDisabledMode:
                 with obs.span("hot", {"lo": 0}):
                     pass
                 obs.metrics.inc("hot", 1.0)
+                # engine.run's span sites as they are written: no attrs
+                with obs.span("engine.plan"):
+                    pass
+                with obs.span("engine.sw_chunk"):
+                    with obs.span("engine.dispatch"):
+                        pass
+                    with obs.span("engine.fetch"):
+                        pass
+                with obs.span("engine.finalize"):
+                    pass
             after = tracemalloc.take_snapshot()
         finally:
             tracemalloc.stop()
@@ -128,6 +149,63 @@ class TestDisabledMode:
                         "obs" in (fr.filename or "")
                         for fr in s.traceback))
         assert grown == 0, f"disabled obs hot path allocated {grown} bytes"
+
+    def test_engine_run_builds_no_span_attrs_while_off(self, monkeypatch):
+        """Every span site of engine.run passes no attrs while tracing is
+        off: a site costs the one bool check inside span()."""
+        calls = []
+        real = obs.span
+
+        def spy(name, attrs=None):
+            calls.append((name, attrs))
+            return real(name, attrs)
+
+        monkeypatch.setattr(obs, "span", spy)
+        dm, g = _matrix_study()
+        engine.run(dm, g, n_perms=99, chunk=40)     # streaming, 3 chunks
+        engine.run(dm, g, n_perms=99)               # one batch
+        assert {name for name, _ in calls} == set(ENGINE_SPANS)
+        assert all(attrs is None for _, attrs in calls)
+        assert obs.events() == []
+
+
+class TestEngineSpans:
+    @pytest.mark.parametrize("chunk,n_chunks", [(40, 3), (None, 1)])
+    def test_host_phases_nest(self, chunk, n_chunks):
+        obs.enable(trace=True, metrics=False)
+        dm, g = _matrix_study()
+        engine.run(dm, g, n_perms=99, chunk=chunk)
+        ev = sorted(obs.events(), key=lambda e: e["ts"])
+        assert {e["name"] for e in ev} == set(ENGINE_SPANS)
+        top = [e["name"] for e in ev if e["args"]["depth"] == 0]
+        assert top == ["engine.plan", "engine.sw", "engine.finalize"]
+
+        def under(name, parent, depth):
+            es = [e for e in ev if e["name"] == name]
+            assert len(es) == n_chunks
+            assert all(e["args"]["depth"] == depth
+                       and e["args"]["parent"] == parent for e in es)
+            return es
+
+        chunks = under("engine.sw_chunk", "engine.sw", 1)
+        dispatch = under("engine.dispatch", "engine.sw_chunk", 2)
+        fetch = under("engine.fetch", "engine.sw_chunk", 2)
+        for c, d, f in zip(chunks, dispatch, fetch):
+            end = c["ts"] + c["dur"]
+            assert c["ts"] <= d["ts"] <= d["ts"] + d["dur"] <= f["ts"]
+            assert f["ts"] + f["dur"] <= end + 1e-3
+
+    def test_device_memory_read_by_report_not_by_entries(self,
+                                                         monkeypatch):
+        calls = []
+        monkeypatch.setattr(jaxhooks, "record_device_memory",
+                            lambda: calls.append(1))
+        obs.enable(trace=True, metrics=True)
+        dm, g = _matrix_study()
+        engine.run(dm, g, n_perms=99, chunk=40)
+        assert calls == []
+        obs.report(file=None)
+        assert calls == [1]
 
 
 class TestCounters:
@@ -158,6 +236,23 @@ class TestCounters:
         assert obs.metrics.value(jaxhooks.RETRACES) == before
         f(jnp.ones((9,))).block_until_ready()      # new shape: re-jit
         assert obs.metrics.value(jaxhooks.RETRACES) >= before + 1
+
+    def test_backend_compiles_counted_by_program(self):
+        obs.enable(trace=False, metrics=True)
+
+        @jax.jit
+        def _compile_probe(v):
+            return v * 3.0 + 1.0
+
+        total = obs.metrics.value(jaxhooks.BACKEND_COMPILES)
+        name = f"{jaxhooks.BACKEND_COMPILES}.jit(_compile_probe)"
+        _compile_probe(jnp.ones((7,))).block_until_ready()
+        assert obs.metrics.value(name) == 1.0
+        assert obs.metrics.value(jaxhooks.BACKEND_COMPILES) >= total + 1
+        _compile_probe(jnp.ones((7,))).block_until_ready()   # warm
+        assert obs.metrics.value(name) == 1.0
+        _compile_probe(jnp.ones((5,))).block_until_ready()   # new shape
+        assert obs.metrics.value(name) == 2.0
 
     def test_merge_snapshots_psum_free(self):
         hosts = [
