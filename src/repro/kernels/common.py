@@ -57,14 +57,17 @@ def pick_tile(v: int, cap: int, align: int = 8) -> int:
     return int(cap) if v > cap else round_up(v, align)
 
 
-def onehot_t(g, n_groups_pad: int):
+def onehot_t(g, n_groups_pad: int, on=None):
     """(PB, T) int32 labels -> (PB * n_groups_pad, T) f32 one-hot whose row
-    p * n_groups_pad + k is [g[p] == k]. n_groups_pad is a multiple of 8,
-    so the collapse of the two leading axes is tile aligned."""
+    p * n_groups_pad + k is [g[p] == k] (or `on` where it is 1, a scalar
+    folded in at no extra pass). n_groups_pad is a multiple of 8, so the
+    collapse of the two leading axes is tile aligned."""
     pb, t = g.shape
     k = jax.lax.broadcasted_iota(jnp.int32, (pb, n_groups_pad, t), 1)
-    return (g[:, None, :] == k).astype(jnp.float32).reshape(
-        pb * n_groups_pad, t)
+    hit = g[:, None, :] == k
+    e = (hit.astype(jnp.float32) if on is None
+         else jnp.where(hit, on, jnp.float32(0.0)))
+    return e.reshape(pb * n_groups_pad, t)
 
 
 def dot(a, b, dims=(((1,), (0,)), ((), ()))):

@@ -16,11 +16,15 @@ Three dataflows, mirroring the paper's study (DESIGN.md section 2):
              one-hot matmul, so each mat^2 tile feeds a (TR,TC)x(TC,G*P)
              systolic contraction. Arithmetic intensity ~P*G/2 flop/byte —
              past the v5e ridge point for P*G >= ~512 (see DESIGN.md sec. 3).
+             The whole matrix runs grid = (perm-block, listed tile pair)
+             over the tile pairs j >= i only (Algorithm 3's cols > rows
+             bound at tile granularity); a row slab runs (perm-block,
+             row-tile, col-tile) over every tile.
 
 Grid convention (TPU): the LAST grid dimension is innermost. All kernels
-accumulate over the (row-tile, col-tile) inner dims into an output block
-indexed only by the outer perm dim — the Pallas-safe write-once-per-block
-accumulation pattern (init at first inner step via pl.when).
+accumulate over the inner tile dims into an output block indexed only by
+the outer perm dim — the Pallas-safe write-once-per-block accumulation
+pattern (init at first inner step via pl.when).
 
 Layout (what Mosaic accepts): every value is 2-D. Label blocks are
 (rows, tile) with the sample axis on lanes; the sample axis a mask needs
@@ -41,6 +45,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -179,36 +184,62 @@ def sw_permblock_pallas(mat2, groupings, w, *, perm_block, tile_r, tile_c,
 
 
 # ---------------------------------------------------------------------------
-# matmul: grid (n_perm_blocks, nti, ntj); MXU one-hot contraction
+# matmul: grid (n_perm_blocks, triangle tile pairs) or (n_perm_blocks, nti,
+# ntj); MXU one-hot contraction
 # ---------------------------------------------------------------------------
 
-def _sw_matmul_body(g_row_ref, g_col_ref, m2_ref, o_ref, acc_ref, *,
-                    n_groups_pad: int, nti: int, ntj: int):
-    i = pl.program_id(1)
-    j = pl.program_id(2)
+def triangle_pairs(nt: int):
+    """The (row tile, column tile, weight) step tables of the upper
+    triangle of an nt x nt tile grid: the pairs j >= i, row-major (the row
+    label block keeps its index across a row), weight 2 off the diagonal
+    and 1 on it. D^2 is symmetric and both sides carry the same labels,
+    so tile (j, i) totals what tile (i, j) does."""
+    ti, tj = (np.asarray(v, np.int32) for v in np.triu_indices(nt))
+    return ti, tj, np.where(ti == tj, 1, 2).astype(np.int32)
 
-    @pl.when((i == 0) & (j == 0))
+
+def _sw_matmul_body(*refs, n_groups_pad: int, listed: bool, ntj: int,
+                    n_steps: int):
+    if listed:        # grid (perm block, listed pair); tables lead the refs
+        _, _, wt_ref, g_row_ref, g_col_ref, m2_ref, o_ref, acc_ref = refs
+        t = pl.program_id(1)
+        # the pair's weight rides on the row one-hot, which is built anyway
+        on = wt_ref[t].astype(jnp.float32)
+    else:             # grid (perm block, row tile, column tile)
+        g_row_ref, g_col_ref, m2_ref, o_ref, acc_ref = refs
+        t = pl.program_id(1) * ntj + pl.program_id(2)
+        on = None
+
+    @pl.when(t == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     m2 = m2_ref[...]                                             # (TR, TC)
-    e_r = _c.onehot_t(g_row_ref[...], n_groups_pad)              # (W, TR)
+    e_r = _c.onehot_t(g_row_ref[...], n_groups_pad, on=on)       # (W, TR)
     e_c = _c.onehot_t(g_col_ref[...], n_groups_pad).astype(m2.dtype)
     # MXU: (W, TC) x (TR, TC)^T -> (W, TR), W = perm_block * groups
     acc_ref[...] += _c.dot(e_c, m2, _c.DOT_NT) * e_r
 
-    @pl.when((i == nti - 1) & (j == ntj - 1))
+    @pl.when(t == n_steps - 1)
     def _flush():
         o_ref[...] = jnp.sum(acc_ref[...], axis=1, keepdims=True).T[None]
 
 
-def sw_matmul_pallas(mat2, g_rows, g_cols, *, n_groups_pad, perm_block,
-                     tile_r, tile_c, interpret, name):
+def sw_matmul_pallas(mat2, g_rows, g_cols, *, triangle, n_groups_pad,
+                     perm_block, tile_r, tile_c, interpret, name):
     """Per-(perm, group) same-group sums over the (i != j) entries of mat2.
 
     mat2 (nr, nc) is the whole matrix or a slab of its rows; g_rows
     (P, nr) and g_cols (P, nc) are the permuted labels of those rows and
     of all columns. mat2 may be bf16 (accumulation is always fp32).
+
+    triangle (whole matrix, g_rows the same as g_cols, square tiles): the
+    grid is (perm block, listed tile pair) over triangle_pairs, whose
+    tables ride in SMEM as scalar prefetch and steer the block index
+    maps; off-diagonal tiles weigh 2 for the mirror tile they stand for.
+    Otherwise the grid is (perm block, row tile, column tile) over every
+    tile, weight 1 (a listed step costs ~1-2 % more on v5e, so a full
+    sweep keeps the plain grid).
     Returns (P, n_groups_pad) f32 totals: s_W is 0.5 * totals @
     inv_group_sizes (the zero diagonal makes the halved symmetric sum
     exact; row slabs' totals add up to the whole matrix's). `name` is the
@@ -217,20 +248,35 @@ def sw_matmul_pallas(mat2, g_rows, g_cols, *, n_groups_pad, perm_block,
     nti, ntj = mat2.shape[0] // tile_r, mat2.shape[1] // tile_c
     npb = n_perms // perm_block
     width = perm_block * n_groups_pad
+    blocks = [((perm_block, tile_r), lambda p, i, j: (p, i)),
+              ((perm_block, tile_c), lambda p, i, j: (p, j)),
+              ((tile_r, tile_c), lambda p, i, j: (i, j))]
+    out_block = ((1, 1, width), lambda p, i, j: (p, 0, 0))
+    scratch = [pltpu.VMEM((width, tile_r), jnp.float32)]
+    if triangle:
+        steps = tuple(jnp.asarray(v) for v in triangle_pairs(nti))
+        n_steps = steps[0].shape[0]
+
+        def at(f):    # a listed step t stands for the pair (ti[t], tj[t])
+            return lambda p, t, ti, tj, wt: f(p, ti[t], tj[t])
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(steps), grid=(npb, n_steps),
+            in_specs=[pl.BlockSpec(b, at(f)) for b, f in blocks],
+            out_specs=pl.BlockSpec(out_block[0], at(out_block[1])),
+            scratch_shapes=scratch)
+    else:
+        steps, n_steps = (), nti * ntj
+        grid_spec = pl.GridSpec(
+            grid=(npb, nti, ntj),
+            in_specs=[pl.BlockSpec(b, f) for b, f in blocks],
+            out_specs=pl.BlockSpec(*out_block), scratch_shapes=scratch)
     kernel = functools.partial(_sw_matmul_body, n_groups_pad=n_groups_pad,
-                               nti=nti, ntj=ntj)
+                               listed=triangle, ntj=ntj, n_steps=n_steps)
     out = pl.pallas_call(
         kernel,
-        grid=(npb, nti, ntj),
-        in_specs=[
-            pl.BlockSpec((perm_block, tile_r), lambda p, i, j: (p, i)),
-            pl.BlockSpec((perm_block, tile_c), lambda p, i, j: (p, j)),
-            pl.BlockSpec((tile_r, tile_c), lambda p, i, j: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, width), lambda p, i, j: (p, 0, 0)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((npb, 1, width), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((width, tile_r), jnp.float32)],
         interpret=interpret,
         name=name,
-    )(g_rows, g_cols, mat2)
+    )(*steps, g_rows, g_cols, mat2)
     return out.reshape(n_perms, n_groups_pad)

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 
 from repro.kernels import common as _c
 from repro.kernels.permanova_sw import kernel as _k
+from repro.obs import metrics as _metrics
 
 VARIANTS = ("brute", "permblock", "matmul")
 
@@ -66,7 +67,7 @@ def permanova_sw(mat2, groupings, inv_group_sizes, *, variant="matmul",
         return _matmul_sw(mat2, groupings, groupings, w,
                           perm_block=perm_block, tile_r=tile_r,
                           tile_c=tile_c, interpret=interpret,
-                          name=_k.SW_NAME)
+                          name=_k.SW_NAME, square=True)
     n = mat2.shape[0]
     n_perms = groupings.shape[0]
     tile_r = _c.pick_tile(n, tile_r)
@@ -86,11 +87,19 @@ def permanova_sw(mat2, groupings, inv_group_sizes, *, variant="matmul",
 
 
 def _matmul_sw(mat2, g_rows, g_cols, w, *, perm_block, tile_r, tile_c,
-               interpret, name):
+               interpret, name, square=False):
     """s_W (P,) of mat2 (nr, nc) — the whole matrix or a slab of its rows
     — through sw_matmul_pallas. Pads rows, columns and permutations to
     the blocks (zero mat2 pad contributes nothing; pad permutations repeat
-    the last and are sliced off) and weights the per-group totals."""
+    the last and are sliced off) and weights the per-group totals.
+
+    square: mat2 is the whole symmetric matrix and g_rows is g_cols, so
+    the kernel visits only the upper triangle of tiles (off-diagonal ones
+    weighted 2) when the tiles are square too; otherwise every tile. The
+    totals are the full symmetric sum either way, hence the 0.5 below.
+    The share of the tile grid the kernel visits goes to the
+    `sw.tile_share` gauge when the call is traced (the host knows the
+    shapes then)."""
     nr, nc = mat2.shape
     n_perms = g_cols.shape[0]
     tile_r = _c.pick_tile(nr, tile_r)
@@ -106,12 +115,16 @@ def _matmul_sw(mat2, g_rows, g_cols, w, *, perm_block, tile_r, tile_c,
     if p_pad:
         g_rows, g_cols = (jnp.pad(g, ((0, p_pad), (0, 0)), mode="edge")
                           for g in (g_rows, g_cols))
+    triangle = square and tile_r == tile_c
+    nt = mat2.shape[0] // tile_r
+    _metrics.gauge_set("sw.tile_share",
+                       (nt + 1) / (2 * nt) if triangle else 1.0)
     n_groups = w.shape[0]
     gp = _c.round_up(n_groups, 8)
-    tot = _k.sw_matmul_pallas(mat2, g_rows, g_cols, n_groups_pad=gp,
-                              perm_block=perm_block, tile_r=tile_r,
-                              tile_c=tile_c, interpret=interpret,
-                              name=name)
+    tot = _k.sw_matmul_pallas(mat2, g_rows, g_cols, triangle=triangle,
+                              n_groups_pad=gp, perm_block=perm_block,
+                              tile_r=tile_r, tile_c=tile_c,
+                              interpret=interpret, name=name)
     w = jnp.pad(w, (0, gp - n_groups))
     return 0.5 * jnp.sum(tot[:n_perms] * w, axis=1)
 

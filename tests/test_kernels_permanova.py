@@ -18,6 +18,9 @@ SHAPES = [
     (96, 4, 6, 32, 3),
     (130, 2, 5, 32, 4),     # ragged: padding path
     (57, 7, 9, 16, 16),     # perm_block > n_perms
+    (24, 3, 5, 32, 8),      # one tile (nt = 1): the triangle is one step
+    (48, 17, 6, 16, 4),     # 17 groups pad to 24 one-hot rows; nt = 3
+    (200, 17, 10, 64, 8),   # 17 groups, ragged, nt = 4
 ]
 
 
@@ -55,6 +58,41 @@ def test_kernel_bf16_within_tolerance(variant):
         tile_r=32, tile_c=32, perm_block=4))
     rel = np.max(np.abs(got - ref64) / np.maximum(np.abs(ref64), 1e-6))
     assert rel < 5e-3, f"bf16 matmul rel err {rel}"
+
+
+@pytest.mark.parametrize("n,g,p,tile", [(24, 3, 5, 32), (64, 4, 6, 32),
+                                         (90, 17, 9, 32)])
+def test_triangle_matches_full_tile_list(n, g, p, tile):
+    """The whole matrix (upper triangle of tiles, weights 2 and 1) and the
+    row-slab entry over all of it (the plain grid over every tile)
+    agree."""
+    mat2, gperms, inv_gs = _instance(n, g, p, seed=n * g)
+    tri = np.asarray(ops.permanova_sw(mat2, gperms, inv_gs, variant="matmul",
+                                      tile_r=tile, tile_c=tile,
+                                      perm_block=8))
+    full = np.asarray(ops.sw_matmul_rows_partial(
+        mat2, 0, gperms, inv_gs, tile_r=tile, tile_c=tile, perm_block=8))
+    np.testing.assert_allclose(tri, full, rtol=1e-6)
+
+
+def test_tile_share_gauge():
+    """sw.tile_share: nt(nt+1)/2 / nt^2 after a square call with square
+    tiles, 1.0 after a row-slab call or with unequal tiles."""
+    from repro import obs
+    mat2, gperms, inv_gs = _instance(80, 3, 7, seed=5)
+    obs.enable(trace=False, metrics=True)
+    try:
+        ops.permanova_sw(mat2, gperms, inv_gs, variant="matmul", tile_r=16,
+                         tile_c=16, perm_block=8)
+        assert obs.metrics.gauge_value("sw.tile_share") == 15 / 25
+        ops.sw_matmul_rows_partial(mat2[:48], 16, gperms, inv_gs,
+                                   tile_r=16, tile_c=16, perm_block=8)
+        assert obs.metrics.gauge_value("sw.tile_share") == 1.0
+        ops.permanova_sw(mat2, gperms, inv_gs, variant="matmul", tile_r=16,
+                         tile_c=40, perm_block=8)
+        assert obs.metrics.gauge_value("sw.tile_share") == 1.0
+    finally:
+        obs.disable()
 
 
 def test_kernels_agree_with_each_other():

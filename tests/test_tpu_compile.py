@@ -22,6 +22,7 @@ import pytest
 PAPER_N_PAD = 25344          # 25145 rounded up to the 256 tile
 P_TOTAL = 4000               # 3999 permutations + the observed labels
 N_GROUPS = 8
+CELL_GROUPS = 17             # the paper cell's EMPO level 3
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 INSTRUCTION = re.compile(
@@ -90,8 +91,9 @@ def _compile_text(fn, *specs):
     return jax.jit(fn).lower(*specs).compile().as_text()
 
 
+@pytest.mark.parametrize("n_groups", [N_GROUPS, CELL_GROUPS])
 @pytest.mark.parametrize("variant", ["matmul", "permblock", "brute"])
-def test_permanova_sw_kernel_paper_width(one_chip, variant):
+def test_permanova_sw_kernel_paper_width(one_chip, variant, n_groups):
     from repro.engine import registry
     from repro.kernels.permanova_sw import ops
     tuning = dict(registry.get(f"pallas_{variant}").tuning)
@@ -103,7 +105,7 @@ def test_permanova_sw_kernel_paper_width(one_chip, variant):
     txt = _compile_text(
         fn, _spec(one_chip, (PAPER_N_PAD, PAPER_N_PAD), jnp.float32),
         _spec(one_chip, (P_TOTAL, PAPER_N_PAD), jnp.int32),
-        _spec(one_chip, (N_GROUPS,), jnp.float32))
+        _spec(one_chip, (n_groups,), jnp.float32))
     calls = _custom_calls(txt)
     assert len(calls) == 1 and _kernels("sw_roofline").match(calls[0])
 
@@ -185,7 +187,7 @@ def test_distance_kernel_planned_tiles(one_chip, metric, packed):
                    for m in ("sw_roofline", "fusedk_roofline"))
 
 
-CELL_N, CELL_CHUNK, CELL_GROUPS = 25145, 2668, 17
+CELL_N, CELL_CHUNK = 25145, 2668
 
 
 def test_step_program_at_the_paper_cell(one_chip):
