@@ -22,10 +22,14 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs as _obs
 from repro.core import distance as _distance
 from repro.core import fstat, permutations, permanova as _permanova
+from repro.obs import metrics as _metrics
 
 Array = jax.Array
+
+PSUM = "dist.psum"          # device scope of the s_W psum over 'model'
 
 
 def pad_to_multiple(x: Array, multiple: int, axis: int = 0):
@@ -73,8 +77,11 @@ def make_sw_shard_fn(mesh: Mesh, *, impl: str = "matmul",
 
     The row-sharded partial is looked up in the engine registry: the exact
     impl's companion when it has one, else the nearest family member
-    (tiled, pallas_brute -> brute rows; pallas_permblock -> matmul rows)."""
+    (tiled, pallas_brute -> brute rows; pallas_permblock -> matmul rows).
+    The label generation runs under the engine's `engine.labels` scope and
+    the psum under `dist.psum`, so a device trace can tell them apart."""
     from repro import engine  # deferred: engine imports core modules
+    from repro.engine.scheduler import LABELS
     partial_fn = engine.get_sharded(impl)
     tuning_key = ("block" if partial_fn is fstat.sw_rows_partial
                   else "perm_block")
@@ -82,43 +89,95 @@ def make_sw_shard_fn(mesh: Mesh, *, impl: str = "matmul",
     def shard_body(mat2_rows, grouping, inv_gs, key, n_perms_padded):
         n_local = mat2_rows.shape[0]
         row_offset = jax.lax.axis_index("model") * n_local
-        lo, per = _my_perm_range(mesh, n_perms_padded)
-        gperms = permutations.permutation_batch_dyn(
-            key, grouping, lo, per, identity_first=identity_first)
+        with jax.named_scope(LABELS):
+            lo, per = _my_perm_range(mesh, n_perms_padded)
+            gperms = permutations.permutation_batch_dyn(
+                key, grouping, lo, per, identity_first=identity_first)
         part = partial_fn(mat2_rows, row_offset, gperms, inv_gs,
                           **{tuning_key: perm_block})
-        return jax.lax.psum(part, axis_name="model")
+        with jax.named_scope(PSUM):
+            return jax.lax.psum(part, axis_name="model")
 
     return shard_body
+
+
+def _perm_ways(mesh: Mesh) -> int:
+    ways = 1
+    for a in _perm_axes(mesh):
+        ways *= mesh.shape[a]
+    return ways
+
+
+def _sw_shard_map(mesh: Mesh, impl: str, n_groups: int, n_perms_padded: int,
+                  perm_block: int):
+    """The s_W shard_map over row-padded mat2 (not jitted by itself)."""
+    body = make_sw_shard_fn(mesh, impl=impl, n_groups=n_groups,
+                            perm_block=perm_block)
+    return jax.shard_map(
+        functools.partial(body, n_perms_padded=n_perms_padded),
+        mesh=mesh,
+        in_specs=(P("model", None), P(), P(), P()),
+        out_specs=P(_perm_axes(mesh)),
+        # a Pallas partial's kernel body mixes its (varying) blocks with
+        # unvarying iotas, which the varying-axes check rejects
+        check_vma=False,
+    )
+
+
+def _padded_perms(mesh: Mesh, n_perms: int) -> int:
+    return n_perms + ((-n_perms) % _perm_ways(mesh))
 
 
 def sw_distributed(mesh: Mesh, mat2: Array, grouping: Array, inv_gs: Array,
                    key: jax.Array, n_perms: int, *, impl: str = "matmul",
                    perm_block: int = 64) -> Array:
-    """Full-batch distributed s_W. Returns (n_perms_padded,) with the global
-    permutation order; entry 0 is the observed statistic."""
-    perm_axes = _perm_axes(mesh)
-    perm_ways = 1
-    for a in perm_axes:
-        perm_ways *= mesh.shape[a]
-    model_ways = mesh.shape["model"]
-    n_perms_padded = n_perms + ((-n_perms) % perm_ways)
-    mat2p, _ = pad_to_multiple(mat2, model_ways, axis=0)
-    n_groups = inv_gs.shape[0]
-
+    """Full-batch distributed s_W, run eagerly. Returns (n_perms_padded,)
+    with the global permutation order; entry 0 is the observed statistic."""
+    n_groups = int(inv_gs.shape[0])
     impl = resolve_impl(impl, mat2.shape[1], n_perms, n_groups)
-    body = make_sw_shard_fn(mesh, impl=impl, n_groups=n_groups,
-                            perm_block=perm_block)
-    fn = jax.shard_map(
-        functools.partial(body, n_perms_padded=n_perms_padded),
-        mesh=mesh,
-        in_specs=(P("model", None), P(), P(), P()),
-        out_specs=P(perm_axes),
-        # a Pallas partial's kernel body mixes its (varying) blocks with
-        # unvarying iotas, which the varying-axes check rejects
-        check_vma=False,
-    )
-    return fn(mat2p, grouping, inv_gs, key)
+    mat2p, _ = pad_to_multiple(mat2, mesh.shape["model"], axis=0)
+    sw = _sw_shard_map(mesh, impl, n_groups, _padded_perms(mesh, n_perms),
+                       perm_block)
+    return sw(mat2p, grouping, inv_gs, key)
+
+
+@functools.partial(jax.jit, static_argnames=("mesh", "impl", "n_groups",
+                                             "n_total", "perm_block"))
+def _program(dm, grouping, key, *, mesh: Mesh, impl: str, n_groups: int,
+             n_total: int, perm_block: int):
+    """One whole test as one jitted program: mat2 = D∘D, the s_W
+    shard_map and its psum, s_T, F of every permutation and p. jit keys
+    the static arguments, shapes and dtypes, so a warm call compiles
+    nothing. Takes D already row-sharded (n_pad, n) over 'model' with zero
+    pad rows."""
+    n_perms_padded = _padded_perms(mesh, n_total)
+    model_ways = mesh.shape["model"]
+    n = dm.shape[1]
+    # gauges of the traced shapes (set at trace time, as sw.tile_share)
+    _metrics.gauge_set("dist.model_ways", model_ways)
+    _metrics.gauge_set("dist.rows_per_chip", dm.shape[0] // model_ways)
+    _metrics.gauge_set("dist.perms_per_chip",
+                       n_perms_padded // _perm_ways(mesh))
+    mat2 = dm * dm
+    inv_gs = permutations.inv_group_sizes(grouping, n_groups)
+    sw = _sw_shard_map(mesh, impl, n_groups, n_perms_padded, perm_block)
+    s_w_all = sw(mat2, grouping, inv_gs, key)
+    s_t = jnp.sum(mat2) / 2.0 / n          # pad rows are zero
+    f_all = _permanova.f_from_sw(s_w_all, s_t, n, n_groups)
+    return s_w_all, s_t, f_all, _permanova.p_value_from_null(f_all)
+
+
+def _rows_on_mesh(mesh: Mesh, dm) -> Array:
+    """D as the program takes it: rows padded with zeros to a multiple of
+    the 'model' width and sharded P('model', None). A D that is already so
+    (distance_matrix_sharded's) is returned as is."""
+    sharding = NamedSharding(mesh, P("model", None))
+    model_ways = mesh.shape["model"]
+    if (isinstance(dm, jax.Array) and dm.shape[0] % model_ways == 0
+            and dm.sharding.is_equivalent_to(sharding, dm.ndim)):
+        return dm
+    dm, _ = pad_to_multiple(jnp.asarray(dm), model_ways, axis=0)
+    return jax.device_put(dm, sharding)
 
 
 def distance_matrix_sharded(mesh: Mesh, x: Array, metric: str = "braycurtis",
@@ -158,43 +217,53 @@ def distance_matrix_sharded(mesh: Mesh, x: Array, metric: str = "braycurtis",
 def permanova_distributed(mesh: Mesh, dm: Array, grouping: Array, *,
                           n_perms: int = 999, key: Optional[jax.Array] = None,
                           n_groups: Optional[int] = None,
-                          impl: str = "matmul", perm_block: int = 64):
+                          impl: str = "auto", perm_block: int = 64):
     """Distributed full PERMANOVA. Semantics match core.permanova.permanova
     (up to permutation count padding, which only adds extra null draws).
 
     dm is (n, n), or (n_pad, n) with zero pad rows as built by
-    distance_matrix_sharded (already row-sharded over 'model').
+    distance_matrix_sharded (already row-sharded over 'model'). A warm
+    call at the same shapes runs one compiled program (`_program`).
 
     Label normalization routes through the design shim like every other
     entry point; only plain single-factor designs run here (strata /
     covariate / weighted designs shard over the STUDY axis via
-    engine.permanova_many(mesh=...) instead of matrix rows)."""
+    engine.permanova_many(mesh=...) instead of matrix rows).
+
+    Host phases, each an obs span: `engine.dist.sw` (design, plan and the
+    program's dispatch) and `engine.dist.finalize` (the host waits for
+    s_T, F and p)."""
     from repro.core import design as _design  # deferred: light cycle guard
     if key is None:
         key = jax.random.key(0)
-    dm = jnp.asarray(dm)
-    design = _design.Design.from_labels(grouping, n_groups=n_groups)
-    if not design.is_plain_labels:
-        raise ValueError(
-            "permanova_distributed shards matrix rows for plain "
-            "single-factor designs; use engine.permanova_many(mesh=...) "
-            "for strata/covariate/weighted designs")
-    grouping = design.grouping
-    n = dm.shape[1]
-    n_groups = design.n_groups
-    mat2 = dm * dm
-    inv_gs = permutations.inv_group_sizes(grouping, n_groups)
-    s_w_all = sw_distributed(mesh, mat2, grouping, inv_gs, key, n_perms + 1,
-                             impl=impl, perm_block=perm_block)
-    s_t = jnp.sum(mat2) / 2.0 / n          # pad rows are zero
-    f_all = _permanova.f_from_sw(s_w_all, s_t, n, n_groups)
+    with _obs.span("engine.dist.sw"):
+        design = _design.Design.from_labels(grouping, n_groups=n_groups)
+        if not design.is_plain_labels:
+            raise ValueError(
+                "permanova_distributed shards matrix rows for plain "
+                "single-factor designs; use engine.permanova_many(mesh=...) "
+                "for strata/covariate/weighted designs")
+        dm = _rows_on_mesh(mesh, dm)
+        n = dm.shape[1]
+        n_groups = design.n_groups
+        n_total = n_perms + 1
+        impl = resolve_impl(impl, n, n_total, n_groups)
+        out = _program(dm, design.grouping, key, mesh=mesh, impl=impl,
+                       n_groups=n_groups, n_total=n_total,
+                       perm_block=perm_block)
+    with _obs.span("engine.dist.finalize"):
+        s_w_all, s_t, f_all, p_value = jax.block_until_ready(out)
     return _permanova.PermanovaResult(
         f_stat=f_all[0],
-        p_value=_permanova.p_value_from_null(f_all),
+        p_value=p_value,
         s_t=s_t,
         s_w=s_w_all[0],
         f_perms=f_all,
         n_objects=n,
         n_groups=n_groups,
         n_perms=int(f_all.shape[0]) - 1,
+        method=f"permanova_distributed[{impl}]",
+        plan=(f"{impl}[perm_block={perm_block}] rows over "
+              f"model={mesh.shape['model']}, permutations over "
+              f"{_perm_ways(mesh)} way(s); one compiled program"),
     )

@@ -66,21 +66,35 @@ def _elements(shape):
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     # a described-device compile cannot be read back from a persistent
     # cache without the chip; keep the cache out of it
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        desc = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001
         jax.config.update("jax_enable_compilation_cache", prev)
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    yield SingleDeviceSharding(topo.devices[0])
+    yield desc
     jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    """('data', 'model') = (1, 4) over the described host's chips."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh
+    return Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"),
+                axis_types=(AxisType.Auto, AxisType.Auto))
 
 
 def _spec(sharding, shape, dtype):
@@ -128,6 +142,41 @@ def test_pallas_matmul_row_slab_four_chip_width(one_chip):
     calls = _custom_calls(txt)
     assert len(calls) == 1 and _kernels("sw_roofline").match(calls[0])
     assert calls[0].startswith("%sw_matmul_rows_partial")
+
+
+def test_distributed_program_four_chip_width(four_chips, monkeypatch):
+    """The four-chip matrix cell's whole test as one program
+    (core.distributed._program): D of n = 65536 rows over 'model' = 4,
+    17 groups, 1000 permutations on pallas_matmul's row slab. One kernel
+    per chip under the name sw_rows_roofline reads, one all-reduce under
+    the `dist.psum` scope, the label sorts under `engine.labels`, and no
+    chip holds more than 10 GB: its rows of D, D^2 of them and the
+    labels."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.core import distributed
+    from repro.engine import scheduler
+    from repro.kernels import common
+    # this process's backend is the CPU: compile the kernel for the chip
+    monkeypatch.setattr(common, "interpret_mode", lambda interpret=None: False)
+    n = 65536
+    rows = NamedSharding(four_chips, P("model", None))
+    rep = NamedSharding(four_chips, P())
+    compiled = distributed._program.lower(
+        _spec(rows, (n, n), jnp.float32), _spec(rep, (n,), jnp.int32),
+        jax.ShapeDtypeStruct((), jax.random.key(0).dtype, sharding=rep),
+        mesh=four_chips, impl="pallas_matmul", n_groups=CELL_GROUPS,
+        n_total=1000, perm_block=64).compile()
+    ins = _instructions(compiled.as_text())
+    calls = _custom_calls(compiled.as_text())
+    assert len(calls) == 1 and _kernels("sw_rows_roofline").match(calls[0])
+    reduces = [i for i in ins if i[3].startswith("all-reduce")]
+    assert reduces and any(distributed.PSUM in i[4] for i in reduces)
+    sorts = [i for i in ins if i[3] == "sort"]
+    assert sorts and all(scheduler.LABELS in i[4] for i in sorts)
+    mem = compiled.memory_analysis()
+    shard = 4 * n * n // 4                 # a chip's rows of D, 4.29 GB
+    assert shard <= mem.argument_size_in_bytes < shard + 1e6
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 10e9
 
 
 @pytest.mark.parametrize("metric", ["braycurtis", "jaccard"])
