@@ -33,9 +33,14 @@ def inv_group_sizes(grouping: Array, n_groups: int) -> Array:
 
 
 def permute_grouping(key: jax.Array, grouping: Array) -> Array:
-    """One random relabeling: grouping composed with a random permutation."""
-    perm = jax.random.permutation(key, grouping.shape[0])
-    return grouping[perm]
+    """One random relabeling: grouping composed with a random permutation.
+
+    The labels ride the shuffle's stable sorts as their payload, so no
+    index permutation is built and gathered through. The draw is
+    `grouping[jax.random.permutation(key, n)]` bit for bit: the shuffle's
+    random sort keys do not depend on what they carry.
+    """
+    return jax.random.permutation(key, grouping)
 
 
 def permutation_batch(key: jax.Array, grouping: Array, lo: int, hi: int,
@@ -161,15 +166,16 @@ def masked_permute_grouping(key: jax.Array, grouping: Array,
     the permutation never mixes pad labels into valid positions — group
     sizes over the valid samples are invariant, exactly as an unpadded
     permutation. Draw: uniform keys on the prefix, +inf on the pad, one
-    stable argsort — positions [0, n_valid) receive a uniform random
-    permutation of themselves, the pad suffix maps to itself in order.
-    `n_valid` may be traced (one program serves every study of a ragged
-    batch).
+    stable sort carrying the labels as its payload — positions
+    [0, n_valid) receive a uniform random permutation of themselves, the
+    pad suffix maps to itself in order. The labels are
+    `grouping[jnp.argsort(u)]` bit for bit, with no gather. `n_valid` may
+    be traced (one program serves every study of a ragged batch).
     """
     n = grouping.shape[0]
     u = jax.random.uniform(key, (n,))
     u = jnp.where(jnp.arange(n) < n_valid, u, jnp.inf)
-    return grouping[jnp.argsort(u)]
+    return jax.lax.sort_key_val(u, grouping)[1]
 
 
 def masked_permutation_batch_dyn(key: jax.Array, grouping: Array,

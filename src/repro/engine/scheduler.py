@@ -12,10 +12,11 @@ One jitted step program serves every chunk (the start index is a traced
 scalar), so the sweep compiles once.
 
 Every step program generates its labels under `jax.named_scope(LABELS)`:
-the key folding, the shuffle's sorts, the `grouping[perm]` gather and the
-identity-first select carry `engine.labels` in their op_name metadata, so
-a device trace can sum the label layer apart from the s_W contraction. A
-scope adds metadata only; the compiled ops are the same.
+the key folding, the shuffle's sorts (which carry the labels as their
+payload) and the identity-first select carry `engine.labels` in their
+op_name metadata, so a device trace can sum the label layer apart from
+the s_W contraction. A scope adds metadata only; the compiled ops are
+the same.
 """
 
 from __future__ import annotations

@@ -1,6 +1,8 @@
 """Core PERMANOVA correctness: every s_W variant against the literal
 Algorithm 1 transcription, full-test statistics, p-value semantics."""
 
+import json
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -122,3 +124,119 @@ class TestPermutations:
         full = np.asarray(permutations.permutation_batch(key, g, 0, 16))
         lo_hi = np.asarray(permutations.permutation_batch(key, g, 4, 12))
         np.testing.assert_array_equal(full[4:12], lo_hi)
+
+
+def _index_gather_batch(key, grouping, lo, chunk, identity_first):
+    """The draw as an index permutation gathered through: the form the
+    labels are pinned to, bit for bit."""
+    n = grouping.shape[0]
+    idx = lo + jnp.arange(chunk)
+    perms = jnp.stack([
+        grouping[jax.random.permutation(jax.random.fold_in(key, i), n)]
+        for i in idx])
+    if identity_first:
+        perms = jnp.where((idx == 0)[:, None], grouping[None, :], perms)
+    return perms
+
+
+class TestLabelsRideTheSort:
+    """The labels are carried through the shuffle's sorts as their
+    payload; they equal the index permutation's gather bit for bit."""
+
+    # 1625 / 1626: either side of the shuffle's step from one sort
+    # round to two
+    @pytest.mark.parametrize("identity_first", [True, False])
+    @pytest.mark.parametrize("n", [1, 2, 7, 1625, 1626, 25145])
+    def test_batch_is_the_index_gather(self, n, identity_first):
+        rng = np.random.default_rng(n)
+        # distinct labels: equal labels mean the same permutation
+        grouping = jnp.asarray(rng.permutation(n), jnp.int32)
+        key = jax.random.key(1234567)
+        step = jax.jit(permutations.permutation_batch_dyn,
+                       static_argnames=("chunk", "identity_first"))
+        for lo in (0, 37):
+            got = step(key, grouping, jnp.int32(lo), chunk=5,
+                       identity_first=identity_first)
+            want = _index_gather_batch(key, grouping, lo, 5, identity_first)
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    @pytest.mark.parametrize("n_valid", [1, 2, 57, 99, 100])
+    def test_masked_draw_is_the_argsort_gather(self, n_valid):
+        n = 100
+        rng = np.random.default_rng(n_valid)
+        grouping = np.full(n, 7, np.int32)          # sentinel on the pad
+        grouping[:n_valid] = rng.integers(0, 5, n_valid)
+        grouping = jnp.asarray(grouping)
+        draw = jax.jit(permutations.masked_permute_grouping)
+
+        def old(key, nv):
+            u = jax.random.uniform(key, (n,))
+            u = jnp.where(jnp.arange(n) < nv, u, jnp.inf)
+            return grouping[jnp.argsort(u)]
+
+        for k in range(5):
+            key = jax.random.fold_in(jax.random.key(99), k)
+            got = np.asarray(draw(key, grouping, jnp.int32(n_valid)))
+            np.testing.assert_array_equal(
+                got, np.asarray(old(key, jnp.int32(n_valid))))
+            np.testing.assert_array_equal(got[n_valid:], 7)
+
+
+OLD_DRAW = r"""
+import json
+import jax, jax.numpy as jnp
+import numpy as np
+from repro import engine
+from repro.core import distance, distributed, permutations
+from repro.data.microbiome import synthetic_study
+from repro.launch.mesh import make_mesh
+
+N, G, PERMS = 203, 5, 99
+x, g = synthetic_study(N, 16, G, effect_size=0.3, seed=5)
+x, g = jnp.asarray(x, jnp.float32), jnp.asarray(g, jnp.int32)
+dm = distance.braycurtis(x)
+mesh = make_mesh((2, 2), ("data", "model"))
+dm_rows = distributed.distance_matrix_sharded(mesh, x, "braycurtis")
+key = jax.random.key(2024)
+
+def runs():
+    out = {}
+    for name, chunk in (("engine.run", None), ("engine.run.chunked", 32)):
+        r = engine.run(dm, g, n_perms=PERMS, key=key, chunk=chunk)
+        out[name] = (np.asarray(r.f_perms), float(r.p_value))
+    r = distributed.permanova_distributed(mesh, dm_rows, g, n_perms=PERMS,
+                                          key=key)
+    out["distributed"] = (np.asarray(r.f_perms), float(r.p_value))
+    return out
+
+new = runs()
+
+def gather_draw(key, grouping):
+    perm = jax.random.permutation(key, grouping.shape[0])
+    return grouping[perm]
+
+permutations.permute_grouping = gather_draw
+jax.clear_caches()
+old = runs()
+print(json.dumps({k: {"f_bits": bool(np.array_equal(new[k][0], old[k][0])),
+                      "n_f": int(new[k][0].shape[0]),
+                      "p": [new[k][1], old[k][1]]} for k in new}))
+"""
+
+
+@pytest.fixture(scope="module")
+def old_draw_runs():
+    from conftest import run_subprocess
+    text = run_subprocess(OLD_DRAW, devices=4, timeout=600)
+    return json.loads(text.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("entry", ["engine.run", "engine.run.chunked",
+                                   "distributed"])
+def test_f_and_p_equal_the_index_gather_draw(old_draw_runs, entry):
+    """At a fixed key, every F and the p of a whole test equal those of
+    the same program drawing its labels by the index gather."""
+    r = old_draw_runs[entry]
+    assert r["n_f"] == 100
+    assert r["f_bits"]
+    assert r["p"][0] == r["p"][1]

@@ -149,9 +149,9 @@ def test_distributed_program_four_chip_width(four_chips, monkeypatch):
     (core.distributed._program): D of n = 65536 rows over 'model' = 4,
     17 groups, 1000 permutations on pallas_matmul's row slab. One kernel
     per chip under the name sw_rows_roofline reads, one all-reduce under
-    the `dist.psum` scope, the label sorts under `engine.labels`, and no
-    chip holds more than 10 GB: its rows of D, D^2 of them and the
-    labels."""
+    the `dist.psum` scope, the label sorts under `engine.labels` and no
+    gather there, and no chip holds more than 10 GB: its rows of D, D^2
+    of them and the labels."""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.core import distributed
     from repro.engine import scheduler
@@ -173,6 +173,8 @@ def test_distributed_program_four_chip_width(four_chips, monkeypatch):
     assert reduces and any(distributed.PSUM in i[4] for i in reduces)
     sorts = [i for i in ins if i[3] == "sort"]
     assert sorts and all(scheduler.LABELS in i[4] for i in sorts)
+    assert not [i for i in ins if scheduler.LABELS in i[4]
+                and i[4].endswith("/gather")]
     mem = compiled.memory_analysis()
     shard = 4 * n * n // 4                 # a chip's rows of D, 4.29 GB
     assert shard <= mem.argument_size_in_bytes < shard + 1e6
@@ -242,9 +244,10 @@ CELL_N, CELL_CHUNK = 25145, 2668
 def test_step_program_at_the_paper_cell(one_chip):
     """The streaming s_W step of the paper cell's plan (pallas_matmul,
     17 groups, 2668-permutation chunks over n = 25145): one kernel under
-    the name the roofline reader matches, the label layer's sorts and
-    gather under the `engine.labels` scope, and the same ops as the step
-    built without the scope."""
+    the name the roofline reader matches, the label layer's two sorts
+    under the `engine.labels` scope and no gather of the chunk's labels
+    (they ride the sorts as payload), and the same ops as the step built
+    without the scope."""
     from repro.core import permutations
     from repro.engine import registry, scheduler
     from repro.kernels.permanova_sw import ops
@@ -276,11 +279,10 @@ def test_step_program_at_the_paper_cell(one_chip):
 
     labels = scheduler.LABELS
     sorts = [i for i in scoped if i[3] == "sort"]
-    gathers = [i for i in scoped if i[3] == "fusion"
-               and i[4].endswith("/gather")
+    gathers = [i for i in scoped if i[4].endswith("/gather")
                and _elements(i[2]) == CELL_CHUNK * CELL_N
                and i[2].startswith("s32")]
-    assert len(sorts) == 2 and gathers
-    assert all(labels in i[4] for i in sorts + gathers)
+    assert len(sorts) == 2 and not gathers
+    assert all(labels in i[4] for i in sorts)
     assert not any(labels in i[4] for i in scoped if i[1] in calls)
     assert [i[0] for i in scoped] == [i[0] for i in plain]
