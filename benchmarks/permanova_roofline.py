@@ -1,5 +1,5 @@
-"""Roofline accounting for the PERMANOVA kernels on the TARGET chip
-(TPU v5e): arithmetic intensity per variant at the paper's shape, and the
+"""Roofline accounting for the PERMANOVA kernels on the TPU v5e:
+arithmetic intensity per variant at the paper's shape, and the
 predicted time per 1000 permutations. This is the quantitative version of
 the paper's CPU-vs-GPU finding, recast for VPU vs MXU (DESIGN.md sec. 2-3).
 """

@@ -8,7 +8,6 @@ Suites:
   stream       paper Appendix A2 — STREAM copy/scale/add/triad
   sweep        paper section 2 workload envelope (n, n_perms scaling)
   pa_roofline  PERMANOVA arithmetic-intensity roofline on TPU v5e
-  roofline     LM-zoo roofline table from dry-run artifacts (deliverable g)
   serve        always-on PERMANOVA serving: studies/sec vs latency SLO,
                p99 from serve.step spans, worker-death recovery overhead
 
@@ -30,8 +29,8 @@ import traceback
 import jax
 
 from benchmarks import (fig1_sw_variants, permanova_roofline,
-                        pipeline_scale, roofline_report, serve_bench,
-                        stream_triad, sweep_scale)
+                        pipeline_scale, serve_bench, stream_triad,
+                        sweep_scale)
 from repro import compile_cache, obs
 
 SUITES = {
@@ -40,7 +39,6 @@ SUITES = {
     "sweep": sweep_scale.run,
     "pipeline": pipeline_scale.run,
     "pa_roofline": permanova_roofline.run,
-    "roofline": roofline_report.run,
     "serve": serve_bench.run,
 }
 

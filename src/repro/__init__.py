@@ -1,4 +1,4 @@
-"""repro — a multi-pod JAX framework reproducing and extending
+"""repro — a JAX/Pallas PERMANOVA system for TPU, reproducing and extending
 *Comparing CPU and GPU compute of PERMANOVA on MI300A* (Sfiligoi, PEARC25).
 
 Layers:
@@ -12,17 +12,14 @@ Layers:
   obs/        zero-dependency telemetry: trace spans (Chrome/Perfetto
               export), compile/traffic counters, predicted-vs-measured
               bandwidth reconciliation (obs.report)
-  models/     assigned LM-architecture zoo (dense / MoE / SSM / hybrid / enc-dec)
-  sharding/   logical-axis -> mesh partition rules
-  train/      training step, microbatching, remat
-  serve/      KV-cache prefill/decode serving
-  optim/      optimizers, schedules, gradient compression
-  data/       synthetic pipelines (tokens + microbiome abundance)
+  serve/      always-on PERMANOVA service (shape buckets, deadlines,
+              batching, degradation)
+  runtime/    fault tolerance: heartbeats, elastic permutation blocks,
+              stragglers, fault injection
   checkpoint/ sharded checkpoints with async write + resume
-  runtime/    fault tolerance: heartbeats, elastic re-mesh, stragglers
-  roofline/   compiled-HLO roofline analysis (compute/memory/collective)
-  configs/    architecture + experiment configs
-  launch/     mesh construction, dry-run, train/serve/permanova drivers
+  data/       synthetic microbiome studies and the on-disk slab cache
+  launch/     mesh construction, permanova/serve drivers
+  hw.py       peak rates of the devices, keyed by device_kind
 """
 
 __version__ = "1.0.0"

@@ -1,7 +1,5 @@
-from repro.data.tokens import SyntheticTokenDataset, make_token_batches  # noqa: F401
 from repro.data.microbiome import (synthetic_abundance,  # noqa: F401
                                    synthetic_sparse_counts, synthetic_study)
-from repro.data.loader import PrefetchLoader, ShardedLoader  # noqa: F401
 from repro.data.slabcache import (SlabCache, SlabCacheError,  # noqa: F401
                                   SlabCacheWriter, SlabPrefetcher,
                                   build_slab_cache)

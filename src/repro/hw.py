@@ -3,8 +3,8 @@
 `device_spec()` returns the entry for the device JAX reports
 (`jax.devices()[0].device_kind`). A device that is not in the table is an
 error: no device borrows another device's numbers. Every entry names its
-source. These are datasheet ceilings for the roofline model and the
-traffic reports, never measurements.
+source. These are datasheet ceilings for the roofline and traffic
+reports, never measurements.
 
 The MI300A constants below are the paper's own measurements. They serve
 the paper-comparison tables (Fig. 1, STREAM) only and model no device
@@ -21,10 +21,7 @@ from typing import Optional
 class ChipSpec:
     name: str
     peak_flops_bf16: float  # FLOP/s per chip
-    peak_flops_f32: float   # FLOP/s per chip (MXU f32 ~= 1/2 bf16 on v5e-class)
     hbm_bandwidth: float    # B/s per chip
-    hbm_bytes: float        # HBM capacity per chip
-    ici_link_bandwidth: float  # B/s per link
     ici_links: int          # links per chip
     vmem_bytes: float       # on-chip vector memory
     source: str = ""
@@ -35,10 +32,7 @@ class ChipSpec:
 TPU_V5E = ChipSpec(
     name="tpu-v5e",
     peak_flops_bf16=197e12,
-    peak_flops_f32=98.5e12,
     hbm_bandwidth=819e9,
-    hbm_bytes=16 * 1024**3,
-    ici_link_bandwidth=50e9,
     ici_links=4,
     vmem_bytes=128 * 1024**2,
     source=("Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
@@ -51,10 +45,7 @@ TPU_V5E = ChipSpec(
 HOST_CPU = ChipSpec(
     name="cpu",
     peak_flops_bf16=1e12,
-    peak_flops_f32=1e12,
     hbm_bandwidth=64e9,
-    hbm_bytes=64 * 1024**3,
-    ici_link_bandwidth=0.0,
     ici_links=0,
     vmem_bytes=32 * 1024**2,
     source="nominal host model for CPU test runs (not measured)",
@@ -93,13 +84,7 @@ MI300A_HBM_PEAK = 5.3e12             # B/s datasheet
 PAPER_N_DIMS = 25145
 PAPER_N_PERMS = 3999
 
-TARGET = TPU_V5E
 
-
-def ridge_point_bf16(chip: ChipSpec = TARGET) -> float:
+def ridge_point_bf16(chip: ChipSpec = TPU_V5E) -> float:
     """FLOP/byte where the chip transitions memory-bound -> compute-bound."""
     return chip.peak_flops_bf16 / chip.hbm_bandwidth
-
-
-def ridge_point_f32(chip: ChipSpec = TARGET) -> float:
-    return chip.peak_flops_f32 / chip.hbm_bandwidth

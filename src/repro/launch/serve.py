@@ -1,37 +1,18 @@
-"""Serving launchers.
+"""Serving launcher.
 
   # always-on PERMANOVA service (chaos smoke: inject a worker death and
   # assert the served result is bit-identical to the failure-free run)
   PYTHONPATH=src python -m repro.launch.serve permanova \
       --studies 6 --workers 3 --inject-death --trace serve_trace.json
-
-  # LM decode demo with continuous batching (legacy entry point; running
-  # without a subcommand defaults here for backward compatibility)
-  PYTHONPATH=src python -m repro.launch.serve lm --arch internlm2-1.8b \
-      --smoke --requests 12 --batch 4 --max-new 16
 """
 
 from __future__ import annotations
 
 import argparse
-import sys
-import time
 
-import jax
 import numpy as np
 
 from repro import compile_cache, obs
-
-
-def _lm_args(ap: argparse.ArgumentParser) -> None:
-    ap.add_argument("--arch", default="internlm2-1.8b")
-    ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--requests", type=int, default=12)
-    ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--max-len", type=int, default=128)
-    ap.add_argument("--max-new", type=int, default=16)
-    ap.add_argument("--temperature", type=float, default=0.8)
-    ap.add_argument("--seed", type=int, default=0)
 
 
 def _pa_args(ap: argparse.ArgumentParser) -> None:
@@ -55,37 +36,6 @@ def _pa_args(ap: argparse.ArgumentParser) -> None:
                          "serial run and zero warm retraces")
     ap.add_argument("--trace", default=None,
                     help="write a Chrome trace of the serve session")
-
-
-def cmd_lm(args: argparse.Namespace) -> int:
-    from repro.configs.registry import ARCHS, SMOKES
-    from repro.models.model import build_model
-    from repro.serve.engine import Request, ServeLoop, temperature_sample
-
-    cfg = (SMOKES if args.smoke else ARCHS)[args.arch]
-    if cfg.family == "encdec":
-        raise SystemExit("use a decoder-only arch for the serve demo")
-    model = build_model(cfg)
-    params = model.init(jax.random.key(args.seed))
-    rng = np.random.default_rng(args.seed)
-    reqs = [Request(prompt=rng.integers(0, cfg.vocab, size=(4,))
-                    .astype(np.int32), max_new_tokens=args.max_new)
-            for _ in range(args.requests)]
-    loop = ServeLoop(model, params, batch_size=args.batch,
-                     max_len=args.max_len,
-                     sampler=temperature_sample(args.temperature))
-    t0 = time.time()
-    done = loop.run(reqs, max_steps=args.max_len * 4,
-                    key=jax.random.key(args.seed))
-    dt = time.time() - t0
-    n_tok = sum(len(r.generated) for r in done)
-    print(f"[serve] arch={cfg.name} requests={len(done)} "
-          f"generated={n_tok} tok wall={dt:.1f}s tok/s={n_tok/dt:.1f}")
-    for i, r in enumerate(done[:3]):
-        print(f"  req{i}: prompt={r.prompt.tolist()} -> "
-              f"{r.generated[:12]}{'...' if len(r.generated) > 12 else ''}")
-    assert all(r.done for r in done), "unfinished requests"
-    return 0
 
 
 def _synth_stream(args: argparse.Namespace) -> list:
@@ -193,19 +143,13 @@ def cmd_permanova(args: argparse.Namespace) -> int:
 
 
 def main(argv=None) -> int:
-    argv = list(sys.argv[1:] if argv is None else argv)
-    # backward compat: `python -m repro.launch.serve --smoke ...` predates
-    # the subcommands and means the LM demo
-    if not argv or argv[0] not in ("lm", "permanova", "-h", "--help"):
-        argv.insert(0, "lm")
     ap = argparse.ArgumentParser(prog="repro.launch.serve")
     sub = ap.add_subparsers(dest="cmd", required=True)
-    _lm_args(sub.add_parser("lm", help="LM decode demo"))
     _pa_args(sub.add_parser(
         "permanova", help="always-on PERMANOVA service smoke"))
     args = ap.parse_args(argv)
     compile_cache.enable()
-    return {"lm": cmd_lm, "permanova": cmd_permanova}[args.cmd](args)
+    return cmd_permanova(args)
 
 
 if __name__ == "__main__":

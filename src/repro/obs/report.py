@@ -6,9 +6,8 @@ the span buffer records how long each stage actually took. `report()`
 pairs the two — predicted bytes / measured wall-time = achieved GB/s —
 and flags stages whose achieved bandwidth falls below a configurable
 fraction of a reference bandwidth (the device's HBM peak from the
-repro.hw table, or $REPRO_OBS_PEAK_GBPS / the `peak_gbps=` argument). This is the measured counterpart of
-roofline/report.py's model-only tables, rendered through the same
-markdown table helper.
+repro.hw table, or $REPRO_OBS_PEAK_GBPS / the `peak_gbps=` argument),
+rendered as column-aligned markdown tables.
 """
 
 from __future__ import annotations
@@ -22,6 +21,18 @@ from repro.obs import metrics as _metrics
 from repro.obs import trace as _trace
 
 PEAK_GBPS_ENV = "REPRO_OBS_PEAK_GBPS"
+
+
+def _render_table(headers, rows):
+    """Column-aligned markdown table."""
+    cells = [list(map(str, headers))] + [list(map(str, r)) for r in rows]
+    widths = [max(len(row[i]) for row in cells)
+              for i in range(len(headers))]
+    def fmt(row):
+        return "| " + " | ".join(c.ljust(w)
+                                 for c, w in zip(row, widths)) + " |"
+    sep = "|" + "|".join("-" * (w + 2) for w in widths) + "|"
+    return "\n".join([fmt(cells[0]), sep] + [fmt(r) for r in cells[1:]])
 
 
 def budget_violations(budgets: Dict[str, float]) -> list:
@@ -100,7 +111,6 @@ def report(*, peak_gbps: Optional[float] = None, flag_fraction: float = 0.5,
     seconds, see budget_violations) — appends a budget-status section,
     flagging every entry over its limit."""
     from repro.obs import jaxhooks
-    from repro.roofline.report import render_table
     # peak device memory is read once here, not on every entry's call
     jaxhooks.record_device_memory()
     ref = (peak_gbps if peak_gbps is not None
@@ -110,7 +120,7 @@ def report(*, peak_gbps: Optional[float] = None, flag_fraction: float = 0.5,
              f"(reference {ref:.1f} GB/s, flag below "
              f"{flag_fraction:.0%} of it):"]
     if rows:
-        lines.append(render_table(
+        lines.append(_render_table(
             ["stage", "calls", "pred MiB", "measured s", "GB/s",
              "of ref", "flag"],
             [[r["stage"], str(r["calls"]), f"{r['predicted_mib']:.2f}",
@@ -126,7 +136,7 @@ def report(*, peak_gbps: Optional[float] = None, flag_fraction: float = 0.5,
              if a["predicted_bytes"] <= 0.0]
     if other:
         lines.append("")
-        lines.append(render_table(
+        lines.append(_render_table(
             ["stage (no traffic model)", "calls", "measured s"],
             [[n, str(a["calls"]), f"{a['total_s']:.4f}"]
              for n, a in other]))

@@ -10,4 +10,3 @@ from repro.runtime.faultinject import (  # noqa: F401
     SimulatedOOM,
     VirtualClock,
 )
-from repro.runtime.trainer import FaultTolerantTrainer  # noqa: F401

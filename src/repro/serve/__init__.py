@@ -1,10 +1,3 @@
-from repro.serve.engine import (  # noqa: F401
-    make_serve_step,
-    make_prefill,
-    ServeLoop,
-    greedy_sample,
-    temperature_sample,
-)
 from repro.serve.permanova import (  # noqa: F401
     PermanovaServer,
     RetryPolicy,

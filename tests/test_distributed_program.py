@@ -167,3 +167,33 @@ def test_label_and_psum_scopes_in_the_compiled_program(four_devices):
     s = four_devices["scopes"]
     assert s["sort_labels"] and all(s["sort_labels"])
     assert s["psum"]
+
+
+DISTRIBUTED_PERMANOVA_CODE = r"""
+import jax, jax.numpy as jnp
+import numpy as np
+from repro.core import distance, permanova
+from repro.core.distributed import permanova_distributed
+from repro.data.microbiome import synthetic_study
+from repro.launch.mesh import make_mesh
+
+x, grouping = synthetic_study(48, 32, 3, effect_size=0.0, seed=7)
+dm = distance.braycurtis(jnp.asarray(x))
+ref = permanova(dm, jnp.asarray(grouping), n_perms=99, sw_impl="brute")
+for shape, names in [((4, 2), ("data", "model")),
+                     ((2, 2, 2), ("pod", "data", "model"))]:
+    mesh = make_mesh(shape, names)
+    for impl in ("brute", "matmul"):
+        r = permanova_distributed(mesh, dm, jnp.asarray(grouping),
+                                  n_perms=99, impl=impl)
+        assert abs(float(r.f_stat) - float(ref.f_stat)) < 1e-4
+        assert abs(float(r.p_value) - float(ref.p_value)) < 1e-6
+print("DIST-PERMANOVA-OK")
+"""
+
+
+@pytest.mark.multidevice
+def test_distributed_permanova_multi_device():
+    from conftest import run_subprocess
+    out = run_subprocess(DISTRIBUTED_PERMANOVA_CODE, devices=8, timeout=600)
+    assert "DIST-PERMANOVA-OK" in out

@@ -3,6 +3,8 @@ oracle (every registered impl, awkward shapes included), planner dispatch
 rules, streaming scheduler equivalence + fixed-memory contract, and the
 batched multi-study API."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -33,6 +35,14 @@ def _instance(n, g, seed=0, n_perms=6):
     gperms = np.asarray(permutations.permutation_batch(
         jax.random.key(seed + 1), jnp.asarray(grouping), 0, n_perms))
     return d, grouping, inv_gs, gperms
+
+
+def _f_err(f, ref, n, n_groups):
+    """Worst |dF| / (F + (n-a)/(a-1)) over the permutations: the relative
+    error of s_W, which F's cancellation does not amplify."""
+    f, ref = np.asarray(f, np.float64), np.asarray(ref, np.float64)
+    return float(np.max(np.abs(f - ref)
+                        / (ref + (n - n_groups) / (n_groups - 1))))
 
 
 class TestRegistryParity:
@@ -162,6 +172,27 @@ class TestStreamingScheduler:
                                    np.asarray(batch.f_perms), rtol=1e-5)
         assert float(stream.p_value) == float(batch.p_value)
 
+    @pytest.mark.parametrize("chunk", ["1", "7", "P", "P+1", "2P"])
+    @pytest.mark.parametrize("n", [37, 64])
+    def test_stream_equals_batch_at_chunk_edges(self, n, chunk):
+        """Chunks of one label, ragged chunks, one short of the whole
+        sweep (its last chunk holds one permutation), exactly the sweep
+        (one chunk: the batch plan) and larger than it, over P = 40
+        permutations and the observed grouping."""
+        p = 40
+        c = {"1": 1, "7": 7, "P": p, "P+1": p + 1, "2P": 2 * p}[chunk]
+        d, grouping, _, _ = _instance(n, 4, seed=n + c)
+        dm, key = jnp.asarray(d), jax.random.key(n)
+        batch = engine.run(dm, grouping, n_perms=p, impl="matmul", key=key)
+        stream = engine.run(dm, grouping, n_perms=p, impl="matmul",
+                            key=key, chunk=c)
+        n_chunks = -(-(p + 1) // c)
+        assert re.search(r"chunks=(\d+)", stream.plan).group(1) == \
+            str(n_chunks), stream.plan
+        assert ("stream" in stream.plan) == (n_chunks > 1), stream.plan
+        assert _f_err(stream.f_perms, batch.f_perms, n, 4) < 1e-5
+        assert float(stream.p_value) == float(batch.p_value)
+
     def test_fixed_memory_contract(self):
         """Large sweep under a small budget: label footprint stays bounded
         and the (n_perms, n) tensor is never materialized."""
@@ -195,6 +226,47 @@ class TestStreamingScheduler:
         obs = fstat.sw_brute_one(jnp.asarray(d * d), jnp.asarray(grouping),
                                  inv_gs)
         np.testing.assert_allclose(float(res.s_w), float(obs), rtol=1e-5)
+
+
+def _f_and_p_float64(d, labels, n_groups):
+    """F of every labelling and p, summed in float64 on the host."""
+    n = d.shape[0]
+    d2 = np.asarray(d, np.float64) ** 2
+    s_t = d2.sum() / (2 * n)
+    f = []
+    for lab in labels:
+        w = 1.0 / np.bincount(lab, minlength=n_groups)[lab]
+        s_w = 0.5 * np.sum(d2 * (lab[:, None] == lab[None, :]) * w[:, None])
+        f.append(((s_t - s_w) / (n_groups - 1)) / (s_w / (n - n_groups)))
+    f = np.asarray(f)
+    return f, (np.sum(f[1:] >= f[0]) + 1) / len(f)
+
+
+class TestPallasMatmulEndToEnd:
+    """engine.run through the one-hot Pallas kernel, the paper cell's
+    path, with 32-tiles so n straddles a tile."""
+
+    @pytest.mark.parametrize("g", [2, 17])
+    @pytest.mark.parametrize("n", [31, 32, 33, 67])
+    def test_f_and_p_match_float64_sum(self, n, g):
+        """F of every permutation and p against a float64 NumPy sum over
+        the documented draw: permutation k relabels by
+        grouping[jax.random.permutation(fold_in(key, k), n)], k = 0 the
+        observed grouping."""
+        p = 99
+        d, grouping, _, _ = _instance(n, g, seed=n * g)
+        key = jax.random.key(n + g)
+        res = engine.run(jnp.asarray(d), grouping, n_perms=p,
+                         impl="pallas_matmul", key=key,
+                         tuning={"tile_r": 32, "tile_c": 32})
+        assert res.plan.startswith("pallas_matmul"), res.plan
+        labels = [grouping] + [
+            grouping[np.asarray(jax.random.permutation(
+                jax.random.fold_in(key, k), n))] for k in range(1, p + 1)]
+        f64, p64 = _f_and_p_float64(d, np.stack(labels), g)
+        assert res.f_perms.shape == (p + 1,)
+        assert _f_err(res.f_perms, f64, n, g) < 1e-5
+        assert float(res.p_value) == pytest.approx(p64, abs=1e-7)
 
 
 class TestEntryPoints:

@@ -6,7 +6,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import permutations
+from repro.core import fstat, permutations
+from repro.core.distributed import pad_to_multiple
+from repro.kernels import common
 from repro.kernels.permanova_sw import ops
 from repro.kernels.permanova_sw.ref import sw_ref, sw_ref_f64
 
@@ -73,6 +75,87 @@ def test_triangle_matches_full_tile_list(n, g, p, tile):
     full = np.asarray(ops.sw_matmul_rows_partial(
         mat2, 0, gperms, inv_gs, tile_r=tile, tile_c=tile, perm_block=8))
     np.testing.assert_allclose(tri, full, rtol=1e-6)
+
+
+def _edge_instance(n, g, p, seed):
+    """D (f32, zero diagonal) and p labellings (row 0 the observed one)
+    drawn from g groups, the highest label always present, so the
+    one-hot operand's last real row is used; with g > n some groups are
+    empty and weigh 0."""
+    rng = np.random.default_rng(seed)
+    d = rng.random((n, n)).astype(np.float32)
+    d = (d + d.T) / 2
+    np.fill_diagonal(d, 0.0)
+    grouping = rng.integers(0, g, size=n).astype(np.int32)
+    grouping[-1] = g - 1
+    gperms = np.stack([rng.permutation(grouping) for _ in range(p)])
+    gperms[0] = grouping
+    inv_gs = np.asarray(permutations.inv_group_sizes(
+        jnp.asarray(grouping), g))
+    return d, gperms, inv_gs
+
+
+# the two s_W kernels the benchmark cells run: the square one-hot kernel
+# over the upper triangle of tiles, and the row-slab partial over every
+# tile (here the whole matrix as one slab, row offset 0)
+SW_KERNELS = {
+    "triangle": lambda mat2, gperms, inv_gs, **kw: ops.permanova_sw(
+        mat2, gperms, inv_gs, variant="matmul", **kw),
+    "rows": lambda mat2, gperms, inv_gs, **kw: ops.sw_matmul_rows_partial(
+        mat2, 0, gperms, inv_gs, **kw),
+}
+
+
+def _sw_against_algorithm1(kernel, d, gperms, inv_gs, **kw):
+    got = np.asarray(SW_KERNELS[kernel](
+        jnp.asarray(d * d), jnp.asarray(gperms), jnp.asarray(inv_gs), **kw))
+    oracle = fstat.sw_algorithm1_numpy(d, gperms, inv_gs)
+    np.testing.assert_allclose(got, oracle, rtol=5e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("kernel", sorted(SW_KERNELS))
+@pytest.mark.parametrize("g", [8, 9, 16, 24, 25, 33])
+@pytest.mark.parametrize("n", [32, 33, 95])
+def test_group_padding_and_tile_edges(kernel, g, n):
+    """Group counts on either side of a multiple of 8 (the one-hot pads
+    G to round_up(G, 8) rows: 8 -> 8, 9 -> 16, 25 -> 32, 33 -> 40) at n
+    of exactly one 32-tile, one past it, and one short of three."""
+    d, gperms, inv_gs = _edge_instance(n, g, 6, seed=100 * n + g)
+    _sw_against_algorithm1(kernel, d, gperms, inv_gs, tile_r=32, tile_c=32,
+                           perm_block=8)
+
+
+@pytest.mark.parametrize("kernel", sorted(SW_KERNELS))
+@pytest.mark.parametrize("pb,p", [(16, 15), (16, 16), (16, 17),
+                                  (64, 63), (64, 64), (64, 65)])
+def test_perm_block_edges(kernel, pb, p):
+    """Permutation counts one short of, equal to and one past the block:
+    the square kernel's default block (16) and the row-slab kernel's in
+    the four-chip cell (64). Pad permutations repeat the last one and are
+    sliced off."""
+    d, gperms, inv_gs = _edge_instance(40, 5, p, seed=pb + p)
+    _sw_against_algorithm1(kernel, d, gperms, inv_gs, tile_r=32, tile_c=32,
+                           perm_block=pb)
+
+
+@pytest.mark.parametrize("slabs", [1, 2, 3, 4])
+@pytest.mark.parametrize("n", [48, 49], ids=["even", "padded"])
+def test_row_slabs_sum_to_oracle(slabs, n):
+    """D's rows split into `slabs` slabs as the row-sharded program takes
+    them (rows zero-padded to a multiple of the slab count, as
+    core.distributed._rows_on_mesh pads them; n=49 leaves 1, 2 and 3 pad
+    rows at 2, 3 and 4 slabs): the slab partials at their row offsets sum
+    to the whole s_W. 17 groups, as in the four-chip cell."""
+    d, gperms, inv_gs = _edge_instance(n, 17, 5, seed=slabs * n)
+    rows, pad = pad_to_multiple(jnp.asarray(d * d), slabs, axis=0)
+    assert rows.shape[0] == common.round_up(n, slabs) == n + pad
+    n_local = rows.shape[0] // slabs
+    parts = [np.asarray(ops.sw_matmul_rows_partial(
+        rows[k * n_local:(k + 1) * n_local], k * n_local,
+        jnp.asarray(gperms), jnp.asarray(inv_gs), tile_r=16, tile_c=16,
+        perm_block=8)) for k in range(slabs)]
+    oracle = fstat.sw_algorithm1_numpy(d, gperms, inv_gs)
+    np.testing.assert_allclose(sum(parts), oracle, rtol=5e-5, atol=1e-5)
 
 
 def test_tile_share_gauge():

@@ -11,6 +11,7 @@ import pytest
 from repro.core import fstat, permutations, s_total, f_from_sw, \
     p_value_from_null, permanova
 from repro.core.permanova import SW_IMPLS
+from repro.data import synthetic_study
 
 N_PERMS = 9
 
@@ -107,6 +108,40 @@ class TestFullTest:
                         key=jax.random.key(11))
         assert float(res.p_value) > 0.05
 
+    @pytest.mark.parametrize("n_perms", [1, 9, 99, 999])
+    def test_all_ties_give_p_of_one_exactly(self, n_perms):
+        """Every off-diagonal distance 1.0 and four groups of 8: every
+        partial sum is exact in float32, so every permutation's F is the
+        observed F bit for bit and the >= rule counts all of them."""
+        n, size = 32, 8
+        dm = jnp.ones((n, n), jnp.float32) - jnp.eye(n, dtype=jnp.float32)
+        grouping = jnp.repeat(jnp.arange(n // size, dtype=jnp.int32), size)
+        res = permanova(dm, grouping, n_perms=n_perms,
+                        sw_impl="pallas_matmul", key=jax.random.key(n_perms))
+        f = np.asarray(res.f_perms)
+        assert f.shape == (n_perms + 1,)
+        np.testing.assert_array_equal(f, np.full_like(f, f[0]))
+        assert float(res.p_value) == 1.0
+
+
+@pytest.mark.parametrize("f_perms,p", [
+    ([2.0, 2.0, 2.0, 1.0, 1.5, 2.0, 0.5, 3.0], 5 / 8),
+    ([1.0, 2.0, 3.0, 4.0, 5.0, 6.0, 7.0, 8.0], 1.0),
+    ([9.0, 1.0, 2.0, 3.0, 4.0, 5.0, 6.0, 7.0], 1 / 8),
+], ids=["ties_at_f0", "all_above", "all_below"])
+def test_p_value_from_null_counts_ties(f_perms, p):
+    """(#{F_k >= F_0} + 1) / (P + 1) over 7 permutations, index 0 the
+    observed F: ties count as at least as extreme."""
+    assert float(p_value_from_null(jnp.asarray(f_perms, jnp.float32))) == p
+
+
+def test_synthetic_study_effect_controls_structure():
+    x0, g0 = synthetic_study(40, 30, 2, effect_size=0.0, seed=0)
+    x1, g1 = synthetic_study(40, 30, 2, effect_size=5.0, seed=0)
+    np.testing.assert_array_equal(g0, g1)
+    assert x1.sum() > x0.sum()          # planted bump adds abundance
+    assert x0.shape == (40, 30)
+
 
 class TestPermutations:
     def test_group_sizes_invariant(self, small_study):
@@ -198,12 +233,22 @@ dm = distance.braycurtis(x)
 mesh = make_mesh((2, 2), ("data", "model"))
 dm_rows = distributed.distance_matrix_sharded(mesh, x, "braycurtis")
 key = jax.random.key(2024)
+studies = {}
+for n_groups in (2, 17):
+    xs, gs = synthetic_study(N, 16, n_groups, effect_size=0.3, seed=5)
+    studies[n_groups] = (distance.braycurtis(jnp.asarray(xs, jnp.float32)),
+                         jnp.asarray(gs, jnp.int32))
 
 def runs():
     out = {}
     for name, chunk in (("engine.run", None), ("engine.run.chunked", 32)):
         r = engine.run(dm, g, n_perms=PERMS, key=key, chunk=chunk)
         out[name] = (np.asarray(r.f_perms), float(r.p_value))
+    for n_groups, (dm_s, g_s) in studies.items():
+        for chunk in (1, 7, PERMS + 1):
+            r = engine.run(dm_s, g_s, n_perms=PERMS, key=key, chunk=chunk)
+            out[f"chunk{chunk}.groups{n_groups}"] = (np.asarray(r.f_perms),
+                                                     float(r.p_value))
     r = distributed.permanova_distributed(mesh, dm_rows, g, n_perms=PERMS,
                                           key=key)
     out["distributed"] = (np.asarray(r.f_perms), float(r.p_value))
@@ -231,8 +276,12 @@ def old_draw_runs():
     return json.loads(text.strip().splitlines()[-1])
 
 
-@pytest.mark.parametrize("entry", ["engine.run", "engine.run.chunked",
-                                   "distributed"])
+@pytest.mark.parametrize("entry", [
+    "engine.run", "engine.run.chunked", "distributed",
+    # chunks of one permutation, ragged chunks, and the whole sweep in one
+    # chunk (P + 1 = 100 labels), at 2 groups and at the paper's 17
+    "chunk1.groups2", "chunk7.groups2", "chunk100.groups2",
+    "chunk1.groups17", "chunk7.groups17", "chunk100.groups17"])
 def test_f_and_p_equal_the_index_gather_draw(old_draw_runs, entry):
     """At a fixed key, every F and the p of a whole test equal those of
     the same program drawing its labels by the index gather."""
