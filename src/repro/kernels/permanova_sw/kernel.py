@@ -16,6 +16,10 @@ Three dataflows, mirroring the paper's study (DESIGN.md section 2):
              one-hot matmul, so each mat^2 tile feeds a (TR,TC)x(TC,G*P)
              systolic contraction. Arithmetic intensity ~P*G/2 flop/byte —
              past the v5e ridge point for P*G >= ~512 (see DESIGN.md sec. 3).
+             Passes: the one-hot is 0/1, exact in bf16; an f32 tile is
+             split exactly into three bf16 parts, so a step is three bf16
+             MXU passes (Precision.HIGHEST would take six), a bf16 tile
+             one.
              The whole matrix runs grid = (perm-block, listed tile pair)
              over the tile pairs j >= i only (Algorithm 3's cols > rows
              bound at tile granularity); a row slab runs (perm-block,
@@ -198,6 +202,16 @@ def triangle_pairs(nt: int):
     return ti, tj, np.where(ti == tj, 1, 2).astype(np.int32)
 
 
+def split_bf16x3(x):
+    """f32 x -> bf16 (hi, mid, lo) with hi + mid + lo == x exactly for
+    every normal f32 (three 8-bit significands cover f32's 24): each part
+    is the rounding of what the parts before it leave."""
+    hi = x.astype(jnp.bfloat16)
+    r = x - hi.astype(jnp.float32)
+    mid = r.astype(jnp.bfloat16)
+    return hi, mid, (r - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+
+
 def _sw_matmul_body(*refs, n_groups_pad: int, listed: bool, ntj: int,
                     n_steps: int):
     if listed:        # grid (perm block, listed pair); tables lead the refs
@@ -214,11 +228,21 @@ def _sw_matmul_body(*refs, n_groups_pad: int, listed: bool, ntj: int,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    m2 = m2_ref[...]                                             # (TR, TC)
     e_r = _c.onehot_t(g_row_ref[...], n_groups_pad, on=on)       # (W, TR)
-    e_c = _c.onehot_t(g_col_ref[...], n_groups_pad).astype(m2.dtype)
-    # MXU: (W, TC) x (TR, TC)^T -> (W, TR), W = perm_block * groups
-    acc_ref[...] += _c.dot(e_c, m2, _c.DOT_NT) * e_r
+    # 0/1 is exact in bf16, so the column one-hot is a one-pass operand
+    e_c = _c.onehot_t(g_col_ref[...], n_groups_pad).astype(jnp.bfloat16)
+    # MXU: (W, TC) x (k TR, TC)^T -> (W, k TR), W = perm_block * groups,
+    # the tile's k bf16 parts stacked down the rows: k passes, each
+    # product exact (HIGHEST would split both operands, six passes); the
+    # parts' (W, TR) sums then add up
+    m2 = m2_ref[...]
+    parts = split_bf16x3(m2) if m2.dtype == jnp.float32 else (m2,)
+    tr = m2.shape[0]
+    full = _c.dot(e_c, jnp.concatenate(parts), _c.DOT_NT)
+    prod = full[:, :tr]
+    for k in range(1, len(parts)):
+        prod += full[:, k * tr:(k + 1) * tr]
+    acc_ref[...] += prod * e_r
 
     @pl.when(t == n_steps - 1)
     def _flush():
@@ -232,6 +256,11 @@ def sw_matmul_pallas(mat2, g_rows, g_cols, *, triangle, n_groups_pad,
     mat2 (nr, nc) is the whole matrix or a slab of its rows; g_rows
     (P, nr) and g_cols (P, nc) are the permuted labels of those rows and
     of all columns. mat2 may be bf16 (accumulation is always fp32).
+    Passes: the column one-hot is built in bf16 (0/1, exact); an f32
+    mat2 tile is split exactly into bf16 hi + mid + lo (split_bf16x3),
+    stacked down the rows and contracted in one bf16 dot, three passes,
+    whose three f32 slices are summed: every product is HIGHEST's and
+    only the order of the f32 sums differs. A bf16 mat2 takes one pass.
 
     triangle (whole matrix, g_rows the same as g_cols, square tiles): the
     grid is (perm block, listed tile pair) over triangle_pairs, whose

@@ -99,7 +99,8 @@ def _matmul_sw(mat2, g_rows, g_cols, w, *, perm_block, tile_r, tile_c,
     totals are the full symmetric sum either way, hence the 0.5 below.
     The share of the tile grid the kernel visits goes to the
     `sw.tile_share` gauge when the call is traced (the host knows the
-    shapes then)."""
+    shapes then), and the bf16 MXU passes of each step (3 for f32 mat2,
+    1 for bf16: kernel.split_bf16x3) to `sw.mxu_passes`."""
     nr, nc = mat2.shape
     n_perms = g_cols.shape[0]
     tile_r = _c.pick_tile(nr, tile_r)
@@ -119,6 +120,7 @@ def _matmul_sw(mat2, g_rows, g_cols, w, *, perm_block, tile_r, tile_c,
     nt = mat2.shape[0] // tile_r
     _metrics.gauge_set("sw.tile_share",
                        (nt + 1) / (2 * nt) if triangle else 1.0)
+    _metrics.gauge_set("sw.mxu_passes", 3 if mat2.dtype == jnp.float32 else 1)
     n_groups = w.shape[0]
     gp = _c.round_up(n_groups, 8)
     tot = _k.sw_matmul_pallas(mat2, g_rows, g_cols, triangle=triangle,

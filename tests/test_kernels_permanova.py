@@ -9,7 +9,7 @@ import pytest
 from repro.core import fstat, permutations
 from repro.core.distributed import pad_to_multiple
 from repro.kernels import common
-from repro.kernels.permanova_sw import ops
+from repro.kernels.permanova_sw import kernel, ops
 from repro.kernels.permanova_sw.ref import sw_ref, sw_ref_f64
 
 SHAPES = [
@@ -174,6 +174,64 @@ def test_tile_share_gauge():
         ops.permanova_sw(mat2, gperms, inv_gs, variant="matmul", tile_r=16,
                          tile_c=40, perm_block=8)
         assert obs.metrics.gauge_value("sw.tile_share") == 1.0
+    finally:
+        obs.disable()
+
+
+def _split_values(kind):
+    if kind == "instance":
+        return _instance(200, 17, 1, seed=11)[0]
+    if kind == "binades":
+        rng = np.random.default_rng(12)
+        mant = rng.random(4096) + 1.0
+        return jnp.asarray((mant * 10.0 ** rng.uniform(-30, 30, 4096))
+                           .astype(np.float32).reshape(32, 128))
+    return jnp.zeros((16, 128), jnp.float32)
+
+
+@pytest.mark.parametrize("kind", ["instance", "binades", "zeros"])
+def test_split_bf16x3_reconstructs_f32(kind):
+    """hi + mid + lo is the f32 tile bit for bit: squared distances of a
+    test instance, values spread over 1e-30 .. 1e30, and zeros."""
+    x = _split_values(kind)
+    parts = jax.jit(kernel.split_bf16x3)(x)
+    assert all(p.dtype == jnp.bfloat16 for p in parts)
+    hi, mid, lo = (np.asarray(p, np.float64) for p in parts)
+    back = (hi + mid + lo).astype(np.float32)
+    np.testing.assert_array_equal(back.view(np.uint32),
+                                  np.asarray(x).view(np.uint32))
+    assert np.all(np.abs(lo) <= np.abs(mid))
+
+
+@pytest.mark.parametrize("kernel_name", sorted(SW_KERNELS))
+def test_f32_kernel_matches_float64(kernel_name):
+    """The f32 one-hot kernel (three bf16 passes over the split tile) is
+    within 1e-6 of a float64 sum, 17 groups; on the same instance the
+    hi part alone (one pass over bf16(mat2)) misses by more than 1e-4."""
+    mat2, gperms, inv_gs = _instance(96, 17, 8, seed=17)
+    ref = sw_ref_f64(mat2, gperms, inv_gs)
+    kw = dict(tile_r=32, tile_c=32, perm_block=8)
+    got = np.asarray(SW_KERNELS[kernel_name](mat2, gperms, inv_gs, **kw))
+    hi = np.asarray(SW_KERNELS[kernel_name](mat2.astype(jnp.bfloat16),
+                                            gperms, inv_gs, **kw))
+    assert np.max(np.abs(got - ref) / np.abs(ref)) < 1e-6
+    assert np.max(np.abs(hi - ref) / np.abs(ref)) > 1e-4
+
+
+@pytest.mark.parametrize("dtype,passes", [(jnp.float32, 3),
+                                          (jnp.bfloat16, 1)])
+@pytest.mark.parametrize("kernel_name", sorted(SW_KERNELS))
+def test_mxu_passes_gauge(kernel_name, dtype, passes):
+    """sw.mxu_passes: the bf16 MXU passes a step of the one-hot kernel
+    takes, 3 for f32 mat2 (the exact split) and 1 for bf16."""
+    from repro import obs
+    mat2, gperms, inv_gs = _instance(72, 3, 5, seed=6)
+    obs.enable(trace=False, metrics=True)
+    try:
+        obs.metrics.gauge_set("sw.mxu_passes", 0)
+        SW_KERNELS[kernel_name](mat2.astype(dtype), gperms, inv_gs,
+                                tile_r=16, tile_c=16, perm_block=8)
+        assert obs.metrics.gauge_value("sw.mxu_passes") == passes
     finally:
         obs.disable()
 

@@ -9,6 +9,7 @@ outgrows VMEM fails here instead of on the chip. Interpret mode is
 switched off explicitly: the backend of this process is the CPU.
 """
 
+import contextlib
 import functools
 import importlib.util
 import math
@@ -105,6 +106,19 @@ def _compile_text(fn, *specs):
     return jax.jit(fn).lower(*specs).compile().as_text()
 
 
+@contextlib.contextmanager
+def _mxu_passes():
+    """Yields a callable reading the `sw.mxu_passes` gauge that the
+    one-hot kernel sets while the body is traced (0 if nothing set it)."""
+    from repro import obs
+    obs.enable(trace=False, metrics=True)
+    try:
+        obs.metrics.gauge_set("sw.mxu_passes", 0)
+        yield lambda: obs.metrics.gauge_value("sw.mxu_passes")
+    finally:
+        obs.disable()
+
+
 @pytest.mark.parametrize("n_groups", [N_GROUPS, CELL_GROUPS])
 @pytest.mark.parametrize("variant", ["matmul", "permblock", "brute"])
 def test_permanova_sw_kernel_paper_width(one_chip, variant, n_groups):
@@ -116,10 +130,12 @@ def test_permanova_sw_kernel_paper_width(one_chip, variant, n_groups):
         return ops.permanova_sw(m2, g, w, variant=variant, interpret=False,
                                 **tuning)
 
-    txt = _compile_text(
-        fn, _spec(one_chip, (PAPER_N_PAD, PAPER_N_PAD), jnp.float32),
-        _spec(one_chip, (P_TOTAL, PAPER_N_PAD), jnp.int32),
-        _spec(one_chip, (n_groups,), jnp.float32))
+    with _mxu_passes() as passes:
+        txt = _compile_text(
+            fn, _spec(one_chip, (PAPER_N_PAD, PAPER_N_PAD), jnp.float32),
+            _spec(one_chip, (P_TOTAL, PAPER_N_PAD), jnp.int32),
+            _spec(one_chip, (n_groups,), jnp.float32))
+        assert passes() == (3 if variant == "matmul" else 0)
     calls = _custom_calls(txt)
     assert len(calls) == 1 and _kernels("sw_roofline").match(calls[0])
 
@@ -136,9 +152,11 @@ def test_pallas_matmul_row_slab_four_chip_width(one_chip):
         return ops.sw_matmul_rows_partial(m2_rows, rows, g, w,
                                           interpret=False, **tuning)
 
-    txt = _compile_text(fn, _spec(one_chip, (rows, n), jnp.float32),
-                        _spec(one_chip, (P_TOTAL, n), jnp.int32),
-                        _spec(one_chip, (N_GROUPS,), jnp.float32))
+    with _mxu_passes() as passes:
+        txt = _compile_text(fn, _spec(one_chip, (rows, n), jnp.float32),
+                            _spec(one_chip, (P_TOTAL, n), jnp.int32),
+                            _spec(one_chip, (N_GROUPS,), jnp.float32))
+        assert passes() == 3
     calls = _custom_calls(txt)
     assert len(calls) == 1 and _kernels("sw_roofline").match(calls[0])
     assert calls[0].startswith("%sw_matmul_rows_partial")
@@ -161,11 +179,13 @@ def test_distributed_program_four_chip_width(four_chips, monkeypatch):
     n = 65536
     rows = NamedSharding(four_chips, P("model", None))
     rep = NamedSharding(four_chips, P())
-    compiled = distributed._program.lower(
-        _spec(rows, (n, n), jnp.float32), _spec(rep, (n,), jnp.int32),
-        jax.ShapeDtypeStruct((), jax.random.key(0).dtype, sharding=rep),
-        mesh=four_chips, impl="pallas_matmul", n_groups=CELL_GROUPS,
-        n_total=1000, perm_block=64).compile()
+    with _mxu_passes() as passes:
+        compiled = distributed._program.lower(
+            _spec(rows, (n, n), jnp.float32), _spec(rep, (n,), jnp.int32),
+            jax.ShapeDtypeStruct((), jax.random.key(0).dtype, sharding=rep),
+            mesh=four_chips, impl="pallas_matmul", n_groups=CELL_GROUPS,
+            n_total=1000, perm_block=64).compile()
+        assert passes() == 3
     ins = _instructions(compiled.as_text())
     calls = _custom_calls(compiled.as_text())
     assert len(calls) == 1 and _kernels("sw_rows_roofline").match(calls[0])
